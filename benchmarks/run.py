@@ -36,13 +36,16 @@ jit compile hook installed; each module executes inside its own
 ``TRANSFER.scope()`` so the per-module transfer snapshot is the module's
 own traffic.  At exit the global metrics registry plus the per-module
 transfer snapshots land in ``BENCH_metrics.json`` — the nightly metrics
-artifact that rides next to the other ``BENCH_*.json`` files.
+artifact that rides next to the other ``BENCH_*.json`` files.  Compiled
+programs persist in ``$JAX_COMPILATION_CACHE_DIR`` or, unset, in
+``.jax_cache/`` of the checkout (``repro.compile_cache``).
 """
 import json
 import sys
 import time
 
 from repro import obs
+from repro.compile_cache import use_compile_cache
 from repro.engine import TRANSFER
 from repro.obs import install_compile_hook
 
@@ -59,6 +62,7 @@ ENTRY = {"perf_iterate": "run_engine"}
 
 def main() -> None:
     want = sys.argv[1:] or MODULES
+    use_compile_cache()
     obs.enable()
     install_compile_hook()
     transfer_per_module = {}

@@ -223,7 +223,7 @@ def _first_obj_of_subpaths(objects, lengths, shard, Hp1):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("check_capacity", "pol", "use_pallas"),
+    static_argnames=("check_capacity", "pol", "use_pallas", "mesh"),
     donate_argnums=(0, 1),
 )
 def _fused_update_batch(
@@ -243,6 +243,7 @@ def _fused_update_batch(
     check_capacity: bool,
     pol,                     # resolved non-home-first policy or None (static)
     use_pallas: bool,
+    mesh=None,               # path-axis Mesh of a sharded batch (static)
 ):
     """One *fused* UPDATE round: gate + candidate scoring + bit-test +
     scatter-OR in a single dispatch, batch statistics reduced on device
@@ -254,7 +255,10 @@ def _fused_update_batch(
     With ``use_pallas`` the whole round runs as the
     ``kernels.provision_update`` megakernel (capacity checking falls back
     to the jnp core: the marginal-load einsum needs the full [B, C, S]
-    plane the lane kernel deliberately never materializes).
+    plane the lane kernel deliberately never materializes).  With a
+    ``mesh`` the megakernel runs once per device on its slice of the
+    batch (a Mosaic kernel cannot be partitioned by GSPMD); the
+    scatter-OR stays in GSPMD.
 
     Pad rows (length 0, t 0) are inert in every statistic — h = 0 means
     the empty window costs 0 and C(0, t)'s first candidate accepts — so
@@ -263,11 +267,12 @@ def _fused_update_batch(
     from repro.engine.backends import gate_counts  # lazy: no cycle at import
 
     if use_pallas and not check_capacity:
+        from repro.kernels import interpret_pallas
         from repro.kernels.provision_update import fused_update_pallas
 
         words, costs, failed, chosen, srv, skipped = fused_update_pallas(
             words, objects, lengths, shard, f, tables, counts, t, rank,
-            pol=pol,
+            pol=pol, interpret=interpret_pallas(), mesh=mesh,
         )
         first_obj = _first_obj_of_subpaths(
             objects, lengths, shard, tables.shape[2]
@@ -277,10 +282,17 @@ def _fused_update_batch(
         if pol is None:
             h_routed = jnp.zeros_like(t)
         else:
-            h_routed = gate_counts(
-                objects, lengths, words, shard, pol, rank,
-                backend="pallas" if use_pallas else "jnp",
-            )
+            def gate(objects, lengths, words, shard, rank):
+                return gate_counts(
+                    objects, lengths, words, shard, pol, rank,
+                    backend="pallas" if use_pallas else "jnp",
+                )
+
+            if use_pallas and mesh is not None:
+                from repro.engine.sharding import map_paths
+
+                gate = map_paths(gate, mesh, (True, True, False, False, False))
+            h_routed = gate(objects, lengths, words, shard, rank)
         (
             words, costs, failed, chosen, first_obj, srv, new_load, skipped
         ) = _update_batch_core(
@@ -424,7 +436,7 @@ def _run_update_batches(
     pol=None,
     rank=None,
     use_pallas: bool = False,
-    put=None,
+    mesh=None,
     acc_holder: DeviceStatsAcc | None = None,
 ):
     """The batched UPDATE loop over vectorizable paths (shared by the
@@ -445,12 +457,12 @@ def _run_update_batches(
     ``_fused_update_batch`` step per batch: the gate runs inside the same
     jit (``pol`` + ``rank``), stats accumulate in a device vector read
     once at the end, and ``use_pallas`` lowers the round to the
-    ``kernels.provision_update`` megakernel.  ``put`` overrides the
-    host->device upload (the sharded driver installs a mesh-aware put so
-    batches land path-sharded across devices).  ``acc_holder`` defers
-    even that one end-of-call readback: the device stat vector is carried
-    in the holder across calls (streamed ingestion) and drained once by
-    the caller — the stat components stay 0 in ``stats`` until then.
+    ``kernels.provision_update`` megakernel.  ``mesh`` (fused only)
+    uploads every batch path-sharded across its devices.  ``acc_holder``
+    defers even that one end-of-call readback: the device stat vector is
+    carried in the holder across calls (streamed ingestion) and drained
+    once by the caller — the stat components stay 0 in ``stats`` until
+    then.
 
     Mutates ``packed`` (donated words) and ``stats``; returns the final
     device load and, when ``collect_additions``, the applied (object,
@@ -459,7 +471,7 @@ def _run_update_batches(
     add_obj: list[np.ndarray] = []
     add_srv: list[np.ndarray] = []
     nb = len(vec_objects)
-    put = to_device if put is None else put
+    put = to_device
     if fused:
         if acc_holder is not None and acc_holder.acc is not None:
             acc = acc_holder.acc
@@ -467,6 +479,19 @@ def _run_update_batches(
             acc = jnp.zeros((3,), jnp.float32)
         if rank is None:
             rank = jnp.zeros((packed.words.shape[1] * 32,), jnp.float32)
+    if mesh is not None:
+        from repro.engine import sharding as _sharding
+
+        # the words and load are replicated on the mesh for this loop
+        # only: the host-driven gate, revalidation and prune outside it
+        # run Pallas kernels on one device (GSPMD cannot partition a
+        # Mosaic kernel), and a later budget class arrives with both
+        # back on one device
+        put = _sharding.batch_put(mesh)
+        packed.words = _sharding.replicate(packed.words, mesh)
+        rank = _sharding.replicate(rank, mesh)
+        load = _sharding.replicate(load, mesh)
+        acc = _sharding.replicate(acc, mesh)
     for i in range(0, nb, batch_size):
         o = vec_objects[i : i + batch_size]
         l = vec_lengths[i : i + batch_size]
@@ -498,6 +523,7 @@ def _run_update_batches(
                 check_capacity,
                 pol,
                 use_pallas,
+                mesh,
             )
         else:
             if routed_fn is not None:
@@ -557,6 +583,9 @@ def _run_update_batches(
             stats.routed_skips += int(a[2])
             if obs.enabled():
                 obs.REGISTRY.counter("repro.greedy.stat_readbacks").inc()
+    if mesh is not None:
+        packed.words = _sharding.one_device(packed.words, mesh)
+        load = _sharding.one_device(load, mesh)
     additions = (
         (
             np.concatenate(add_obj) if add_obj else np.zeros(0, np.int64),
@@ -823,31 +852,21 @@ def _routed_class_filter(
 
 def _fused_setup(packed: PackedScheme, pol, load, fused: bool, mesh,
                  batch_size: int):
-    """Shared fused-driver preamble: the gate holder-rank vector, the
-    (optionally mesh-sharded) batch upload, and the batch size rounded to
-    a device-count multiple.  With a mesh the packed words are replicated
-    across devices here — the single device-resident truth every sharded
-    batch reads and the scatter-OR updates in place.
-    """
+    """Shared fused-driver preamble: the gate holder-rank vector and the
+    batch size rounded to a device-count multiple."""
     if not fused:
         if mesh is not None:
             raise ValueError("mesh= requires fused=True")
-        return None, None, batch_size
+        return None, batch_size
     from repro.engine.backends import _load_vector  # lazy: no cycle at import
 
     rank = _load_vector(
         load if (pol is not None and pol.uses_load) else None, packed.words
     )
-    put = None
     if mesh is not None:
-        from repro.engine import sharding as _sharding
-
-        packed.words = _sharding.replicate(packed.words, mesh)
-        rank = _sharding.replicate(rank, mesh)
-        put = _sharding.batch_put(mesh)
         nd = int(np.prod(list(mesh.shape.values())))
         batch_size = -(-batch_size // nd) * nd
-    return rank, put, batch_size
+    return rank, batch_size
 
 
 def _capacity_arrays(n_servers: int, capacity, epsilon):
@@ -981,7 +1000,7 @@ def _repair_loss_case(
     routed_fn = _routed_gate_fn(masked, pol, policy_backend, load=load)
     fused_c = fused and policy_backend != "reference"
     use_pallas = fused_c and policy_backend == "pallas"
-    rank, put, bsz = _fused_setup(masked, pol, load, fused_c, None, batch_size)
+    rank, bsz = _fused_setup(masked, pol, load, fused_c, None, batch_size)
     srv_load = jnp.asarray(masked.storage_per_server(f_arr).astype(np.float32))
     host_scheme: ReplicationScheme | None = None
     add_obj: list[np.ndarray] = []
@@ -1021,7 +1040,6 @@ def _repair_loss_case(
             pol=pol,
             rank=rank,
             use_pallas=use_pallas,
-            put=put,
         )
         add_obj.append(additions[0])
         add_srv.append(additions[1])
@@ -1290,7 +1308,7 @@ def replicate_workload(
     routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
     fused = fused and policy_backend != "reference"
     use_pallas = policy_backend == "pallas"
-    rank, put, batch_size = _fused_setup(
+    rank, batch_size = _fused_setup(
         packed, pol, load, fused, mesh, batch_size
     )
 
@@ -1329,7 +1347,7 @@ def replicate_workload(
                 pol=pol,
                 rank=rank,
                 use_pallas=use_pallas,
-                put=put,
+                mesh=mesh,
             )
 
             # Exact fallback for enumeration-heavy paths (processed after
@@ -1518,7 +1536,7 @@ def replicate_delta(
     routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
     fused = fused and policy_backend != "reference"
     use_pallas = policy_backend == "pallas"
-    rank, put, batch_size = _fused_setup(
+    rank, batch_size = _fused_setup(
         packed, pol, load, fused, mesh, batch_size
     )
 
@@ -1561,7 +1579,7 @@ def replicate_delta(
                 pol=pol,
                 rank=rank,
                 use_pallas=use_pallas,
-                put=put,
+                mesh=mesh,
                 acc_holder=stats_acc if fused else None,
             )
 

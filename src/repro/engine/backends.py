@@ -7,9 +7,9 @@ Eqns 1-2) — with identical integer semantics:
   ``reference``  pure-python oracle (``repro.core.reference``), host mask.
   ``jnp``        vectorized ``lax.scan`` over the packed device words.
   ``pallas``     ``repro.kernels.path_latency`` TPU kernel (interpret mode
-                 on CPU); inputs are gathered on device from the packed
-                 words, so only the int32 path chunk crosses the host
-                 boundary.
+                 off the TPU, ``repro.kernels.interpret_pallas``);
+                 inputs are gathered on device from the packed words,
+                 so only the int32 path chunk crosses the host boundary.
 
 The legacy unpacked-bool scan (the old ``core.replication``
 ``_path_latencies_jit``) is retained as ``bool_scan`` for the
@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.engine.packed import test_bits
 from repro.engine.routing import resolve_policy
+from repro.kernels import interpret_pallas
 from repro.kernels.path_latency import path_latency_pallas
 
 BACKENDS = ("reference", "jnp", "pallas")
@@ -99,15 +100,11 @@ def pallas_prep(objects, lengths, words, shard):
     return home, masks
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def pallas_eval(objects, lengths, words, shard, block: int = 128):
     """Pallas-backed chunk evaluation; stays on device end to end."""
     home, masks = pallas_prep(objects, lengths, words, shard)
     return path_latency_pallas(
-        home, masks, lengths, block=block, interpret=not _on_tpu()
+        home, masks, lengths, block=block, interpret=interpret_pallas()
     )
 
 
@@ -525,12 +522,12 @@ def gate_counts(objects, lengths, words, shard, pol, rank, backend="jnp",
             scores = _dp_score_tables(objects, lengths, words, _dp_depth(pol))
             _, local = scored_walk_pallas(
                 home, masks, lengths, start, scores,
-                block=block, interpret=not _on_tpu(),
+                block=block, interpret=interpret_pallas(),
             )
         else:
             _, local = routed_walk_pallas(
                 home, masks, lengths, start, rank,
-                block=block, interpret=not _on_tpu(),
+                block=block, interpret=interpret_pallas(),
                 lookahead=pol.lookahead, home_first=pol.name == "home_first",
             )
         L = objects.shape[1]
@@ -652,12 +649,12 @@ def pallas_routed_trace(
         scores = _dp_scores_jit(objects, lengths, words, _dp_depth(pol))
         return scored_walk_pallas(
             home, masks, lengths, start, scores,
-            block=block, interpret=not _on_tpu(),
+            block=block, interpret=interpret_pallas(),
         )
     return routed_walk_pallas(
         home, masks, lengths, start,
         _load_vector(load if pol.uses_load else None, words),
-        block=block, interpret=not _on_tpu(),
+        block=block, interpret=interpret_pallas(),
         lookahead=pol.lookahead, home_first=pol.name == "home_first",
     )
 

@@ -17,11 +17,16 @@ So the layout is the simplest one GSPMD supports:
     XLA inserts automatically (bitwise-OR of the replicated words'
     per-device updates, psum of the stat vector).
 
-``shard_map`` was considered and rejected: the scatter-OR needs a
-bitwise-OR collective over uint32 words, which the manual-collective API
-does not provide — under plain ``jit`` + ``NamedSharding`` GSPMD lowers
-the same program to an all-gather of each device's chosen additions,
-which is tiny (the chosen planes, not the words).
+The Pallas kernels are the exception: a Mosaic kernel is a custom call
+that GSPMD cannot partition (the TPU compiler refuses it), so
+:func:`map_paths` runs the kernel under ``shard_map``, once per device on
+its slice of the path axis.  Only the kernel goes under ``shard_map``: the
+scatter-OR needs a bitwise-OR across devices, which the manual-collective
+API does not provide, so it stays in GSPMD, which lowers it to an
+all-gather of each device's chosen additions — tiny (the chosen planes,
+not the words).  The words are replicated on the mesh only for the fused
+UPDATE loop and return to one device after it (:func:`one_device`): the
+host-driven gate, revalidation and prune call kernels outside that loop.
 
 CPU note: the test/CI environment exposes one device;
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` splits the host
@@ -83,6 +88,29 @@ def replicate(x, mesh: Mesh):
     words/tables are already device-resident; this is a device-to-device
     broadcast, not host traffic)."""
     return jax.device_put(x, replicated(mesh))
+
+
+def one_device(x, mesh: Mesh):
+    """Bring a mesh-placed array back to the mesh's first device."""
+    return jax.device_put(x, mesh.devices.flat[0])
+
+
+def map_paths(fn, mesh: Mesh, sharded: tuple[bool, ...]):
+    """``fn`` once per device on its slice of the path (leading) axis.
+
+    ``sharded[i]`` says whether argument ``i`` is split on the path axis
+    (batch rows) or replicated (words, tables, ranks); every output is
+    split on the path axis.
+    """
+    return jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=tuple(
+            PartitionSpec(PATH_AXIS) if s else PartitionSpec() for s in sharded
+        ),
+        out_specs=PartitionSpec(PATH_AXIS),
+        check_vma=False,
+    )
 
 
 def batch_put(mesh: Mesh):

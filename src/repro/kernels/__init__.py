@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the replication path (+ jnp oracles in ``ref``)."""
+import jax
+
+
+def interpret_pallas() -> bool:
+    """Whether Pallas kernels run in interpret mode: everywhere but a TPU.
+
+    The one platform check behind every kernel call; on a TPU the kernels
+    always lower to Mosaic, never to the interpreter.
+    """
+    return jax.default_backend() != "tpu"

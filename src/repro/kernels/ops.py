@@ -6,16 +6,12 @@ structures (PathSet + ReplicationScheme) to kernel inputs.
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 
+from repro.kernels import interpret_pallas
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.flash_prefill import flash_prefill_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def path_latency(pathset, scheme, block: int = 128) -> np.ndarray:
@@ -35,17 +31,17 @@ def path_latency(pathset, scheme, block: int = 128) -> np.ndarray:
 def decode_attention(q, k, v, lengths, block_t: int = 256):
     """Flash-decode GQA attention (see kernels.decode_attention)."""
     return decode_attention_pallas(
-        q, k, v, lengths, block_t=block_t, interpret=not _on_tpu())
+        q, k, v, lengths, block_t=block_t, interpret=interpret_pallas())
 
 
 def embedding_bag(table, ids, mode: str = "mean"):
     """TBE-style embedding bag (see kernels.embedding_bag)."""
     return embedding_bag_pallas(table, ids, mode=mode,
-                                interpret=not _on_tpu())
+                                interpret=interpret_pallas())
 
 
 def flash_prefill(q, k, v, block_q: int = 128, block_k: int = 128,
                   window: int = 0):
     """Causal flash attention for prefill (see kernels.flash_prefill)."""
     return flash_prefill_pallas(q, k, v, block_q=block_q, block_k=block_k,
-                                window=window, interpret=not _on_tpu())
+                                window=window, interpret=interpret_pallas())

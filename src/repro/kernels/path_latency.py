@@ -6,17 +6,19 @@ causal access paths against the current replication scheme.  The kernel
 evaluates a block of paths per grid step entirely in VMEM.
 
 Layout (TPU-native):  the *path* dimension is the 128-wide lane axis, so
-every op in the position loop is a full-width vector op:
+every op in the position loop is a full-width vector op, and every per-path
+vector is a ``(1, bP)`` row:
 
   home  int32  [L, bP]     home server of the object at each position
                            (-1 padded); bP = 128-aligned path block
-  masks uint32 [L, W, bP]  packed replica-location words per position
-                           (W = ceil(S/32) words, bit s of word w set iff
-                           a copy lives on server 32w+s)
-  lens  int32  [bP]        path lengths
-  out   int32  [bP]        distributed traversals per path
+  masks uint32 [L*W, bP]   packed replica-location words, row x*W + w is
+                           word w of position x (W = ceil(S/32) words, bit
+                           s of word w set iff a copy lives on server 32w+s)
+  lens  int32  [1, bP]     path lengths
+  out   int32  [1, bP]     distributed traversals per path
 
-Per position i (fori_loop, vectorized across the 128 path lanes):
+Per position i (fori_loop, vectorized across the 128 path lanes; rows are
+read from the refs with ``pl.ds(i, 1)``):
   local  = bit test of masks[i] at the current server
   server = local ? server : home[i]
   cost  += valid(i) & ~local
@@ -36,26 +38,32 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK = 128
 
 
+def to_rows(x, block: int, fill=0):
+    """[P, ...] -> [prod(...), Pp] lane-major rows, P padded to ``block``."""
+    pad = (-x.shape[0]) % block
+    if pad:
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                    constant_values=fill)
+    return x.reshape(x.shape[0], -1).T
+
+
 def _kernel(home_ref, mask_ref, len_ref, out_ref):
     L = home_ref.shape[0]
-    W = mask_ref.shape[1]
-    home = home_ref[...]          # [L, bP]
-    masks = mask_ref[...]         # [L, W, bP]
-    lens = len_ref[...]           # [bP]
-
-    server0 = jnp.maximum(home[0], 0)
+    W = mask_ref.shape[0] // L
+    lens = len_ref[...]                          # [1, bP]
+    server0 = jnp.maximum(home_ref[0:1, :], 0)
 
     def body(i, carry):
         server, cost = carry
         valid = (i < lens) & (lens > 0)
-        widx = server // 32
-        bit = (server % 32).astype(jnp.uint32)
-        word = jnp.zeros_like(masks[0, 0])
-        for w in range(W):        # static unroll (W small)
-            word = jnp.where(widx == w, masks[i, w], word)
-        local = ((word >> bit) & jnp.uint32(1)).astype(jnp.bool_)
-        nxt = jnp.where(local, server, jnp.maximum(home[i], 0))
-        nxt = jnp.where(valid, nxt, server)
+        widx = server >> 5                       # server >= 0 throughout
+        bit = (server & 31).astype(jnp.uint32)
+        word = jnp.zeros_like(server, jnp.uint32)
+        for w in range(W):                       # static unroll (W small)
+            word = jnp.where(widx == w, mask_ref[pl.ds(i * W + w, 1), :], word)
+        local = ((word >> bit) & jnp.uint32(1)) != 0
+        home_i = jnp.maximum(home_ref[pl.ds(i, 1), :], 0)
+        nxt = jnp.where(valid, jnp.where(local, server, home_i), server)
         cost = cost + (valid & ~local).astype(jnp.int32)
         return nxt, cost
 
@@ -69,37 +77,33 @@ def path_latency_pallas(
     home: jnp.ndarray,    # int32 [P, L]  home server per position (-1 pad)
     masks: jnp.ndarray,   # uint32 [P, L, W]  packed replica words
     lengths: jnp.ndarray,  # int32 [P]
+    *,
+    interpret: bool,
     block: int = DEFAULT_BLOCK,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Distributed-traversal count per path; see module docstring.
 
     Host-side API keeps the natural [P, L] layout; the kernel uses the
-    lane-transposed layout.  ``interpret=True`` for CPU validation; on TPU
-    pass False.
+    lane-transposed layout.  ``interpret`` comes from
+    ``repro.kernels.interpret_pallas`` (True off the TPU).
     """
     P, L = home.shape
     W = masks.shape[2]
-    pad = (-P) % block
-    if pad:
-        home = jnp.pad(home, ((0, pad), (0, 0)), constant_values=-1)
-        masks = jnp.pad(masks, ((0, pad), (0, 0), (0, 0)))
-        lengths = jnp.pad(lengths, (0, pad))
-    Pp = P + pad
-    home_t = home.T                          # [L, Pp]
-    masks_t = jnp.transpose(masks, (1, 2, 0))  # [L, W, Pp]
+    home_t = to_rows(home, block, fill=-1)       # [L, Pp]
+    masks_t = to_rows(masks, block)              # [L*W, Pp]
+    lens_t = to_rows(lengths, block)             # [1, Pp]
+    Pp = home_t.shape[1]
 
-    grid = (Pp // block,)
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(Pp // block,),
         in_specs=[
             pl.BlockSpec((L, block), lambda p: (0, p)),
-            pl.BlockSpec((L, W, block), lambda p: (0, 0, p)),
-            pl.BlockSpec((block,), lambda p: (p,)),
+            pl.BlockSpec((L * W, block), lambda p: (0, p)),
+            pl.BlockSpec((1, block), lambda p: (0, p)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.int32),
+        out_specs=pl.BlockSpec((1, block), lambda p: (0, p)),
+        out_shape=jax.ShapeDtypeStruct((1, Pp), jnp.int32),
         interpret=interpret,
-    )(home_t, masks_t, lengths)
-    return out[:P]
+    )(home_t, masks_t, lens_t)
+    return out[0, :P]

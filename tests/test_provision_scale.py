@@ -354,6 +354,98 @@ def test_sharded_equals_single_forced_devices():
     assert "SHARDED_OK" in out.stdout
 
 
+# Two budget classes on a 4-device mesh, with every host-driven Pallas
+# kernel wrapper (routed gate, revalidation, prune, repair) refusing a
+# concrete array placed on more than one device: GSPMD cannot partition a
+# Mosaic kernel, so on the chip such an input fails to compile, while
+# interpret mode on forced host devices would run it.  Only the fused
+# UPDATE loop may hold the words on the mesh, and it must take the
+# previous class's load back onto the mesh with them.
+_MESH_CLASSES = """
+import jax
+import numpy as np
+from repro.core.greedy import replicate_delta, replicate_workload
+from repro.engine import LatencyEngine
+from repro.engine import backends
+from repro.engine.sharding import device_count, provisioning_mesh
+from repro.kernels import path_latency, provision_update, routed_walk
+from tests.conftest import random_workload
+
+assert device_count() == 4, device_count()
+calls = {}
+
+
+def one_device(mod, name):
+    fn = getattr(mod, name)
+
+    def guarded(*args, **kwargs):
+        for a in jax.tree_util.tree_leaves((args, kwargs)):
+            if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer):
+                assert len(a.sharding.device_set) == 1, (
+                    f"{name} got an array on {len(a.sharding.device_set)} devices"
+                )
+        calls[name] = calls.get(name, 0) + 1
+        return fn(*args, **kwargs)
+
+    setattr(mod, name, guarded)
+
+
+for mod, name in [
+    (backends, "path_latency_pallas"),
+    (path_latency, "path_latency_pallas"),
+    (routed_walk, "routed_walk_pallas"),
+    (routed_walk, "scored_walk_pallas"),
+    (provision_update, "fused_update_pallas"),
+]:
+    one_device(mod, name)
+
+rng = np.random.default_rng(0)
+n_srv, n_obj, nq = 5, 90, 30
+ps, shard = random_workload(rng, n_obj=n_obj, n_srv=n_srv, n_paths=110,
+                            max_len=6, n_queries=nq)
+t = rng.integers(1, 3, nq)                    # budget classes t = 1 and 2
+assert set(np.unique(t)) == {1, 2}
+delta, _ = random_workload(rng, n_obj=n_obj, n_srv=n_srv, n_paths=60,
+                           max_len=6, n_queries=nq)
+f = rng.uniform(0.5, 2.0, n_obj).astype(np.float32)
+mesh = provisioning_mesh()
+for backend in ("jnp", "pallas"):
+    kw = dict(f=f, policy="nearest_copy", policy_backend=backend, fused=True)
+    single, _ = replicate_workload(ps, shard, n_srv, t, **kw)
+    sharded, _ = replicate_workload(ps, shard, n_srv, t, mesh=mesh, **kw)
+    assert np.array_equal(single.mask, sharded.mask), backend
+    adds = [
+        replicate_delta(delta, LatencyEngine(single.copy()), t, mesh=m, **kw)[1]
+        for m in (None, mesh)
+    ]
+    for a, b in zip(*adds):
+        assert np.array_equal(a, b), backend
+assert calls.get("routed_walk_pallas"), calls
+assert calls.get("fused_update_pallas"), calls
+print("MESH_CLASSES_OK")
+"""
+
+
+def test_mesh_budget_classes_keep_kernels_on_one_device():
+    """Two budget classes through the mesh driver on 4 forced host devices
+    equal the one-device run, and no host-driven kernel gets a mesh-placed
+    array (see ``_MESH_CLASSES``)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
+    ).strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")]
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", _MESH_CLASSES], env=env, cwd=root,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "MESH_CLASSES_OK" in out.stdout
+
+
 # ---------------------------------------------------------------------------
 # tier-1 wall-clock guard
 # ---------------------------------------------------------------------------
