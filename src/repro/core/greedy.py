@@ -47,7 +47,7 @@ from repro.core import combi
 from repro.core.paths import PathSet
 from repro.core.replication import ReplicationScheme, subpath_structure
 from repro.core.reference import update_exact
-from repro.engine import LatencyEngine, PackedScheme, to_device
+from repro.engine import LatencyEngine, PackedScheme, to_device, to_host
 from repro.engine.packed import scatter_or_pairs, test_bits
 
 _INF = jnp.float32(1e30)
@@ -382,7 +382,7 @@ class DeviceStatsAcc:
         """One blocking readback; folds the totals into ``stats``."""
         if self.acc is None:
             return
-        a = np.asarray(self.acc)
+        a = to_host(self.acc)
         stats.total_cost += float(a[0])
         stats.failed_paths += int(a[1])
         stats.routed_skips += int(a[2])
@@ -392,10 +392,14 @@ class DeviceStatsAcc:
 
 
 def _obs_record_class(stats, b, n_vec, n_seq, counts, n_skip) -> None:
-    """Per-budget-class provisioning telemetry (no-op when obs is off)."""
+    """Per-budget-class provisioning timeline (no-op when obs is off).
+
+    The candidate count comes from the tables' shape, not from the device
+    ``counts``: recording reads nothing back."""
     if not obs.enabled():
         return
-    n_cand = int(np.asarray(counts).sum()) if counts is not None else 0
+    n_cand = (sum(combi.n_candidates(h, b) for h in range(counts.shape[0]))
+              if counts is not None else 0)
     if stats.timeline is None:
         stats.timeline = []
     stats.timeline.append({
@@ -405,12 +409,6 @@ def _obs_record_class(stats, b, n_vec, n_seq, counts, n_skip) -> None:
         "n_candidates": n_cand,
         "routed_skips": int(n_skip),
     })
-    reg = obs.REGISTRY
-    reg.counter("repro.greedy.classes").inc()
-    reg.counter("repro.greedy.vec_paths").inc(n_vec)
-    reg.counter("repro.greedy.seq_paths").inc(n_seq)
-    reg.counter("repro.greedy.candidates").inc(n_cand)
-    reg.counter("repro.greedy.routed_skips").inc(n_skip)
 
 
 def _run_update_batches(
@@ -547,9 +545,9 @@ def _run_update_batches(
                 check_capacity,
                 routed_fn is not None,
             )
-            stats.total_cost += float(np.asarray(costs)[:k].sum())
-            stats.failed_paths += int(np.asarray(failed)[:k].sum())
-            stats.routed_skips += int(np.asarray(skipped)[:k].sum())
+            stats.total_cost += float(to_host(costs)[:k].sum())
+            stats.failed_paths += int(to_host(failed)[:k].sum())
+            stats.routed_skips += int(to_host(skipped)[:k].sum())
         if check_capacity:
             # exact load from the packed words, computed on device (the
             # incremental estimate can over-count duplicate additions
@@ -558,14 +556,14 @@ def _run_update_batches(
                 packed.storage_per_server(f_arr).astype(np.float32)
             )
         if track_rm or collect_additions:
-            ch = np.asarray(chosen)[:k]
-            sv = np.asarray(srv)[:k]
+            ch = to_host(chosen)[:k]
+            sv = to_host(srv)[:k]
             bb, xx, kk = np.nonzero(ch)
             if collect_additions:
                 add_obj.append(o[bb, xx].astype(np.int64))
                 add_srv.append(sv[bb, kk].astype(np.int64))
             if track_rm:
-                fo = np.asarray(first_obj)[:k]
+                fo = to_host(first_obj)[:k]
                 for b, x, kk_ in zip(bb, xx, kk):
                     stats.rm.append(
                         (int(fo[b, kk_]), int(o[b, x]), int(sv[b, kk_]))
@@ -577,7 +575,7 @@ def _run_update_batches(
         else:
             # one device->host readback for the whole class (pad rows are
             # inert in every component, see _fused_update_batch)
-            a = np.asarray(acc)
+            a = to_host(acc)
             stats.total_cost += float(a[0])
             stats.failed_paths += int(a[1])
             stats.routed_skips += int(a[2])
@@ -675,7 +673,7 @@ def _budget_class_plan(
         _, _, h_all = subpath_structure(
             jnp.asarray(cls.objects), jnp.asarray(cls.lengths), shard_j
         )
-        h_all = np.asarray(h_all)
+        h_all = to_host(h_all)
         H_needed = int(h_all.max()) if cls.n_paths else 0
         H_vec = combi.max_h_within_budget(b, max_candidates, H_needed)
         vec_idx = np.nonzero(h_all <= H_vec)[0]
@@ -776,7 +774,7 @@ def _routed_gate_fn(packed: PackedScheme, pol, backend: str, block: int = 128,
                 np.asarray(objects, np.int32),
                 np.asarray(lengths, np.int32),
                 packed.unpack(),
-                np.asarray(packed.shard),
+                to_host(packed.shard),
                 policy=pol,
                 load=load,
             )
@@ -791,7 +789,7 @@ def _routed_gate_fn(packed: PackedScheme, pol, backend: str, block: int = 128,
     if backend == "pallas":
 
         def fn(objects, lengths):
-            return np.asarray(
+            return to_host(
                 _backends.pallas_routed_eval(
                     to_device(np.asarray(objects, np.int32)),
                     to_device(np.asarray(lengths, np.int32)),
@@ -806,7 +804,7 @@ def _routed_gate_fn(packed: PackedScheme, pol, backend: str, block: int = 128,
         return fn
 
     def fn(objects, lengths):
-        return np.asarray(
+        return to_host(
             _backends.routed_counts(
                 to_device(np.asarray(objects, np.int32)),
                 to_device(np.asarray(lengths, np.int32)),
@@ -941,7 +939,7 @@ def _resilient_eval(packed: PackedScheme, ps: PathSet, cases, homes,
         load=load,
         backend=policy_backend,
     )
-    return np.asarray(out).astype(np.int64)
+    return to_host(out).astype(np.int64)
 
 
 def _repair_loss_case(
@@ -1121,7 +1119,7 @@ def _enforce_resilience(
     )
 
     n_servers = packed.n_servers
-    shard_host = np.asarray(packed.shard)
+    shard_host = to_host(packed.shard)
     cases = res.loss_cases(n_servers)
     homes = [failover_shard(shard_host, c, n_servers) for c in cases]
     W = int(packed.words.shape[1])
@@ -1171,6 +1169,22 @@ def _enforce_resilience(
     )
 
 
+def _call_span(name: str):
+    """Run the decorated entry point (a PathSet first) as the span ``name``,
+    tagged with the call's path count."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(pathset, *args, **kw):
+            with obs.span(name, paths=pathset.n_paths):
+                return fn(pathset, *args, **kw)
+
+        return run
+
+    return wrap
+
+
+@_call_span("repro.greedy.provision")
 def replicate_workload(
     pathset: PathSet,
     shard: np.ndarray,
@@ -1279,76 +1293,86 @@ def replicate_workload(
     pol = resolve_policy(policy)
     pol = None if pol.name == "home_first" else pol
     res = resolve_resilience(resilience)
-    t_path = normalize_path_budgets(t, pathset)
-    if prune:
-        # the budget joins the §5.3 dedup key: a tight-budget path must not
-        # be merged into a loose-budget duplicate (constraint would vanish)
-        ps, keep = pathset.prune_redundant(
-            shard, extra_key=t_path, return_index=True
+    with obs.span("repro.greedy.init"):
+        t_path = normalize_path_budgets(t, pathset)
+        if prune:
+            # the budget joins the §5.3 dedup key: a tight-budget path must
+            # not be merged into a loose-budget duplicate (constraint would
+            # vanish)
+            ps, keep = pathset.prune_redundant(
+                shard, extra_key=t_path, return_index=True
+            )
+            t_path = t_path[keep]
+        else:
+            ps = pathset
+        scheme = ReplicationScheme.from_sharding(shard, n_servers)
+        stats = GreedyStats(rm=[] if track_rm else None)
+        stats.paths_processed = ps.n_paths
+        if ps.n_paths == 0:
+            stats.runtime_s = time.perf_counter() - t0
+            if return_engine:
+                return scheme, stats, LatencyEngine(scheme)
+            return scheme, stats
+
+        f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
+        packed = PackedScheme.from_sharding(scheme.shard, n_servers)
+        shard_j = packed.shard
+        f_j = to_device(f_arr)
+
+        check_capacity, cap_j, eps_j = _capacity_arrays(
+            n_servers, capacity, epsilon
         )
-        t_path = t_path[keep]
-    else:
-        ps = pathset
-    scheme = ReplicationScheme.from_sharding(shard, n_servers)
-    stats = GreedyStats(rm=[] if track_rm else None)
-    stats.paths_processed = ps.n_paths
-    if ps.n_paths == 0:
-        stats.runtime_s = time.perf_counter() - t0
-        if return_engine:
-            return scheme, stats, LatencyEngine(scheme)
-        return scheme, stats
-
-    f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
-    packed = PackedScheme.from_sharding(scheme.shard, n_servers)
-    shard_j = packed.shard
-    f_j = to_device(f_arr)
-
-    check_capacity, cap_j, eps_j = _capacity_arrays(n_servers, capacity, epsilon)
-    srv_load = jnp.asarray(scheme.storage_per_server(f_arr).astype(np.float32))
-    routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
-    fused = fused and policy_backend != "reference"
-    use_pallas = policy_backend == "pallas"
-    rank, batch_size = _fused_setup(
-        packed, pol, load, fused, mesh, batch_size
-    )
+        srv_load = jnp.asarray(
+            scheme.storage_per_server(f_arr).astype(np.float32)
+        )
+        routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
+        fused = fused and policy_backend != "reference"
+        use_pallas = policy_backend == "pallas"
+        rank, batch_size = _fused_setup(
+            packed, pol, load, fused, mesh, batch_size
+        )
 
     def run_classes(ps_run: PathSet, t_run: np.ndarray) -> None:
         nonlocal srv_load
-        for b, cls, vec_idx, seq_idx, h_all, tables, counts in _budget_class_plan(
-            ps_run, t_run, shard_j, max_candidates,
-            skip_tables=routed_fn is not None, stats=stats,
-        ):
+        with obs.span("repro.greedy.plan"):
+            plan = _budget_class_plan(
+                ps_run, t_run, shard_j, max_candidates,
+                skip_tables=routed_fn is not None, stats=stats,
+            )
+        for b, cls, vec_idx, seq_idx, h_all, tables, counts in plan:
             n_skip = 0
             if routed_fn is not None and cls.n_paths:
-                vec_idx, seq_idx, tables, counts, n_skip = _routed_class_filter(
-                    cls, b, h_all, routed_fn, max_candidates, stats=stats
-                )
+                with obs.span("repro.greedy.filter"):
+                    vec_idx, seq_idx, tables, counts, n_skip = (
+                        _routed_class_filter(cls, b, h_all, routed_fn,
+                                             max_candidates, stats=stats))
                 stats.routed_skips += n_skip
             _obs_record_class(stats, b, len(vec_idx), len(seq_idx), counts, n_skip)
-            srv_load, _ = _run_update_batches(
-                packed,
-                cls.objects[vec_idx],
-                cls.lengths[vec_idx],
-                shard_j,
-                f_arr,
-                f_j,
-                tables,
-                counts,
-                np.full(len(vec_idx), b, np.int32),
-                srv_load,
-                cap_j,
-                eps_j,
-                check_capacity,
-                batch_size,
-                stats,
-                track_rm,
-                routed_fn=None if fused else routed_fn,
-                fused=fused,
-                pol=pol,
-                rank=rank,
-                use_pallas=use_pallas,
-                mesh=mesh,
-            )
+            with obs.span("repro.greedy.batches"):
+                srv_load, _ = _run_update_batches(
+                    packed,
+                    cls.objects[vec_idx],
+                    cls.lengths[vec_idx],
+                    shard_j,
+                    f_arr,
+                    f_j,
+                    tables,
+                    counts,
+                    np.full(len(vec_idx), b, np.int32),
+                    srv_load,
+                    cap_j,
+                    eps_j,
+                    check_capacity,
+                    batch_size,
+                    stats,
+                    track_rm,
+                    routed_fn=None if fused else routed_fn,
+                    fused=fused,
+                    pol=pol,
+                    rank=rank,
+                    use_pallas=use_pallas,
+                    mesh=mesh,
+                )
 
             # Exact fallback for enumeration-heavy paths (processed after
             # the class's vectorized paths; order is immaterial to
@@ -1356,63 +1380,72 @@ def replicate_workload(
             # synced host mask and are replayed into the packed words so
             # later classes see them.
             if len(seq_idx):
-                scheme.mask = packed.unpack()
-                fb_obj: list[int] = []
-                fb_srv: list[int] = []
-                for i in seq_idx:
-                    res = update_exact(
-                        scheme, cls.path(int(i)), b, f_arr, capacity,
-                        epsilon, policy=pol, load=load,
-                    )
-                    stats.fallback_paths += 1
-                    if res.feasible:
-                        stats.total_cost += res.cost
-                        fb_obj.extend(v for v, _ in res.additions)
-                        fb_srv.extend(s for _, s in res.additions)
-                        if track_rm:
-                            stats.rm.extend(res.rm_entries)
-                    else:
-                        stats.failed_paths += 1
-                if fb_obj:
-                    packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
-                    if check_capacity:
-                        srv_load = jnp.asarray(
-                            packed.storage_per_server(f_arr).astype(np.float32)
+                with obs.span("repro.greedy.fallback"):
+                    scheme.mask = packed.unpack()
+                    fb_obj: list[int] = []
+                    fb_srv: list[int] = []
+                    for i in seq_idx:
+                        res = update_exact(
+                            scheme, cls.path(int(i)), b, f_arr, capacity,
+                            epsilon, policy=pol, load=load,
                         )
+                        stats.fallback_paths += 1
+                        if res.feasible:
+                            stats.total_cost += res.cost
+                            fb_obj.extend(v for v, _ in res.additions)
+                            fb_srv.extend(s for _, s in res.additions)
+                            if track_rm:
+                                stats.rm.extend(res.rm_entries)
+                        else:
+                            stats.failed_paths += 1
+                    if fb_obj:
+                        packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
+                        if check_capacity:
+                            srv_load = jnp.asarray(
+                                packed.storage_per_server(f_arr)
+                                .astype(np.float32)
+                            )
 
-    run_classes(ps, t_path)
+    with obs.span("repro.greedy.classes"):
+        run_classes(ps, t_path)
     if routed_fn is not None:
         from repro.engine.incremental import PathIndex  # lazy: no cycle
 
-        _revalidate_routed(
-            routed_fn, ps, t_path, run_classes, stats,
-            index=PathIndex(np.asarray(ps.objects), packed.n_objects),
-        )
+        with obs.span("repro.greedy.revalidate"):
+            _revalidate_routed(
+                routed_fn, ps, t_path, run_classes, stats,
+                index=PathIndex(np.asarray(ps.objects), packed.n_objects),
+            )
 
     # single host readback of the packed words (vs. per-batch bool mask);
     # fallback additions were replayed into the words, so the packed state
     # stays the source of truth and return_engine never loses residency.
-    scheme.mask = packed.unpack()
+    with obs.span("repro.greedy.unpack"):
+        scheme.mask = packed.unpack()
 
     if pol is not None and policy_prune and stats.paths_processed:
         from repro.core.replication import (  # lazy: no cycle at import
             prune_scheme_replicas,
         )
 
-        stats.pruned_replicas, _ = prune_scheme_replicas(
-            scheme, pathset, t, policy=pol, f=f_arr, load=load, fused=fused
-        )
-        if stats.pruned_replicas:
-            # removals are not monotone: the packed words are stale
-            packed = PackedScheme.from_mask(scheme.mask, scheme.shard)
+        with obs.span("repro.greedy.prune"):
+            stats.pruned_replicas, _ = prune_scheme_replicas(
+                scheme, pathset, t, policy=pol, f=f_arr, load=load,
+                fused=fused,
+            )
+            if stats.pruned_replicas:
+                # removals are not monotone: the packed words are stale
+                with obs.span("repro.greedy.prune.repack"):
+                    packed = PackedScheme.from_mask(scheme.mask, scheme.shard)
 
     if res is not None and ps.n_paths:
-        _enforce_resilience(
-            packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
-            capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
-            max_candidates, stats, load, fused, track_rm,
-        )
-        scheme.mask = packed.unpack()
+        with obs.span("repro.greedy.resilience"):
+            _enforce_resilience(
+                packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
+                capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
+                max_candidates, stats, load, fused, track_rm,
+            )
+            scheme.mask = packed.unpack()
 
     stats.replicas = scheme.replica_count()
     stats.runtime_s = time.perf_counter() - t0
@@ -1421,6 +1454,7 @@ def replicate_workload(
     return scheme, stats
 
 
+@_call_span("repro.greedy.delta")
 def replicate_delta(
     pathset: PathSet,
     engine: LatencyEngine,
@@ -1501,87 +1535,96 @@ def replicate_delta(
     from repro.engine.routing import resolve_policy  # local: no cycle at import
 
     t0 = time.perf_counter()
-    if engine.packed is None:
-        engine.packed = PackedScheme.from_mask(
-            engine.scheme.mask, engine.scheme.shard
-        )
-    packed = engine.packed
-    shard = engine.host_shard()
-    n = packed.n_objects
-    n_servers = packed.n_servers
     pol = resolve_policy(policy)
     pol = None if pol.name == "home_first" else pol
     res = resolve_resilience(resilience)
-    t_path = normalize_path_budgets(t, pathset)
-    if prune:
-        ps, keep = pathset.prune_redundant(
-            shard, extra_key=t_path, return_index=True
+    with obs.span("repro.greedy.init"):
+        if engine.packed is None:
+            engine.packed = PackedScheme.from_mask(
+                engine.scheme.mask, engine.scheme.shard
+            )
+        packed = engine.packed
+        shard = engine.host_shard()
+        n = packed.n_objects
+        n_servers = packed.n_servers
+        t_path = normalize_path_budgets(t, pathset)
+        if prune:
+            ps, keep = pathset.prune_redundant(
+                shard, extra_key=t_path, return_index=True
+            )
+            t_path = t_path[keep]
+        else:
+            ps = pathset
+        stats = GreedyStats(rm=[] if track_rm else None)
+        stats.paths_processed = ps.n_paths
+        empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
+        if ps.n_paths == 0:
+            stats.runtime_s = time.perf_counter() - t0
+            return stats, empty
+
+        f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
+        f_j = to_device(f_arr)
+        shard_j = packed.shard
+
+        check_capacity, cap_j, eps_j = _capacity_arrays(
+            n_servers, capacity, epsilon
         )
-        t_path = t_path[keep]
-    else:
-        ps = pathset
-    stats = GreedyStats(rm=[] if track_rm else None)
-    stats.paths_processed = ps.n_paths
-    empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
-    if ps.n_paths == 0:
-        stats.runtime_s = time.perf_counter() - t0
-        return stats, empty
-
-    f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
-    f_j = to_device(f_arr)
-    shard_j = packed.shard
-
-    check_capacity, cap_j, eps_j = _capacity_arrays(n_servers, capacity, epsilon)
-    srv_load = jnp.asarray(packed.storage_per_server(f_arr).astype(np.float32))
-    routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
-    fused = fused and policy_backend != "reference"
-    use_pallas = policy_backend == "pallas"
-    rank, batch_size = _fused_setup(
-        packed, pol, load, fused, mesh, batch_size
-    )
+        srv_load = jnp.asarray(
+            packed.storage_per_server(f_arr).astype(np.float32)
+        )
+        routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
+        fused = fused and policy_backend != "reference"
+        use_pallas = policy_backend == "pallas"
+        rank, batch_size = _fused_setup(
+            packed, pol, load, fused, mesh, batch_size
+        )
 
     add_obj = np.zeros(0, np.int64)
     add_srv = np.zeros(0, np.int64)
 
     def run_classes(ps_run: PathSet, t_run: np.ndarray) -> None:
         nonlocal srv_load, add_obj, add_srv
-        for b, cls, vec_idx, seq_idx, h_all, tables, counts in _budget_class_plan(
-            ps_run, t_run, shard_j, max_candidates,
-            skip_tables=routed_fn is not None, stats=stats,
-        ):
+        with obs.span("repro.greedy.plan"):
+            plan = _budget_class_plan(
+                ps_run, t_run, shard_j, max_candidates,
+                skip_tables=routed_fn is not None, stats=stats,
+            )
+        for b, cls, vec_idx, seq_idx, h_all, tables, counts in plan:
             n_skip = 0
             if routed_fn is not None and cls.n_paths:
-                vec_idx, seq_idx, tables, counts, n_skip = _routed_class_filter(
-                    cls, b, h_all, routed_fn, max_candidates, stats=stats
-                )
+                with obs.span("repro.greedy.filter"):
+                    vec_idx, seq_idx, tables, counts, n_skip = (
+                        _routed_class_filter(cls, b, h_all, routed_fn,
+                                             max_candidates, stats=stats))
                 stats.routed_skips += n_skip
             _obs_record_class(stats, b, len(vec_idx), len(seq_idx), counts, n_skip)
-            srv_load, additions = _run_update_batches(
-                packed,
-                cls.objects[vec_idx],
-                cls.lengths[vec_idx],
-                shard_j,
-                f_arr,
-                f_j,
-                tables,
-                counts,
-                np.full(len(vec_idx), b, np.int32),
-                srv_load,
-                cap_j,
-                eps_j,
-                check_capacity,
-                batch_size,
-                stats,
-                track_rm,
-                collect_additions=collect_additions,
-                routed_fn=None if fused else routed_fn,
-                fused=fused,
-                pol=pol,
-                rank=rank,
-                use_pallas=use_pallas,
-                mesh=mesh,
-                acc_holder=stats_acc if fused else None,
-            )
+            with obs.span("repro.greedy.batches"):
+                srv_load, additions = _run_update_batches(
+                    packed,
+                    cls.objects[vec_idx],
+                    cls.lengths[vec_idx],
+                    shard_j,
+                    f_arr,
+                    f_j,
+                    tables,
+                    counts,
+                    np.full(len(vec_idx), b, np.int32),
+                    srv_load,
+                    cap_j,
+                    eps_j,
+                    check_capacity,
+                    batch_size,
+                    stats,
+                    track_rm,
+                    collect_additions=collect_additions,
+                    routed_fn=None if fused else routed_fn,
+                    fused=fused,
+                    pol=pol,
+                    rank=rank,
+                    use_pallas=use_pallas,
+                    mesh=mesh,
+                    acc_holder=stats_acc if fused else None,
+                )
 
             # Mirror the vectorized additions into the host scheme FIRST:
             # the exact fallback below prices candidates against the host
@@ -1594,68 +1637,74 @@ def replicate_delta(
                     engine.scheme.mask[cls_obj, cls_srv] = True
                 add_obj = np.concatenate([add_obj, cls_obj])
                 add_srv = np.concatenate([add_srv, cls_srv])
-            elif engine.scheme is not None and len(seq_idx):
-                # no per-pair readback requested: the exact fallback below
-                # prices against the host mask, so refresh it from the
-                # packed truth (one readback) right before it is consumed
-                engine.scheme.mask = packed.unpack()
-                if obs.enabled():
-                    obs.REGISTRY.counter("repro.greedy.mask_syncs").inc()
 
             # Exact fallback for enumeration-heavy delta paths: run against
             # a host scheme and replay the additions into the
             # device-resident words.
             if len(seq_idx):
-                host = (
-                    engine.scheme
-                    if engine.scheme is not None
-                    else engine.to_scheme()
-                )
-                fb_obj: list[int] = []
-                fb_srv: list[int] = []
-                for i in seq_idx:
-                    res = update_exact(
-                        host, cls.path(int(i)), b, f_arr, capacity,
-                        epsilon, policy=pol, load=load,
+                with obs.span("repro.greedy.fallback"):
+                    if not collect_additions and engine.scheme is not None:
+                        # no per-pair readback requested: the fallback
+                        # prices against the host mask, so refresh it from
+                        # the packed truth (one readback) right before use
+                        engine.scheme.mask = packed.unpack()
+                        if obs.enabled():
+                            obs.REGISTRY.counter(
+                                "repro.greedy.mask_syncs").inc()
+                    host = (
+                        engine.scheme
+                        if engine.scheme is not None
+                        else engine.to_scheme()
                     )
-                    stats.fallback_paths += 1
-                    if res.feasible:
-                        stats.total_cost += res.cost
-                        fb_obj.extend(v for v, _ in res.additions)
-                        fb_srv.extend(s for _, s in res.additions)
-                        if track_rm:
-                            stats.rm.extend(res.rm_entries)
-                    else:
-                        stats.failed_paths += 1
-                if fb_obj:
-                    packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
-                    if collect_additions:
-                        add_obj = np.concatenate(
-                            [add_obj, np.asarray(fb_obj, np.int64)]
+                    fb_obj: list[int] = []
+                    fb_srv: list[int] = []
+                    for i in seq_idx:
+                        res = update_exact(
+                            host, cls.path(int(i)), b, f_arr, capacity,
+                            epsilon, policy=pol, load=load,
                         )
-                        add_srv = np.concatenate(
-                            [add_srv, np.asarray(fb_srv, np.int64)]
-                        )
-                    if check_capacity:
-                        srv_load = jnp.asarray(
-                            packed.storage_per_server(f_arr).astype(np.float32)
-                        )
+                        stats.fallback_paths += 1
+                        if res.feasible:
+                            stats.total_cost += res.cost
+                            fb_obj.extend(v for v, _ in res.additions)
+                            fb_srv.extend(s for _, s in res.additions)
+                            if track_rm:
+                                stats.rm.extend(res.rm_entries)
+                        else:
+                            stats.failed_paths += 1
+                    if fb_obj:
+                        packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
+                        if collect_additions:
+                            add_obj = np.concatenate(
+                                [add_obj, np.asarray(fb_obj, np.int64)]
+                            )
+                            add_srv = np.concatenate(
+                                [add_srv, np.asarray(fb_srv, np.int64)]
+                            )
+                        if check_capacity:
+                            srv_load = jnp.asarray(
+                                packed.storage_per_server(f_arr)
+                                .astype(np.float32)
+                            )
 
-    run_classes(ps, t_path)
+    with obs.span("repro.greedy.classes"):
+        run_classes(ps, t_path)
     if routed_fn is not None:
         from repro.engine.incremental import PathIndex  # lazy: no cycle
 
-        _revalidate_routed(
-            routed_fn, ps, t_path, run_classes, stats,
-            index=PathIndex(np.asarray(ps.objects), packed.n_objects),
-        )
+        with obs.span("repro.greedy.revalidate"):
+            _revalidate_routed(
+                routed_fn, ps, t_path, run_classes, stats,
+                index=PathIndex(np.asarray(ps.objects), packed.n_objects),
+            )
 
     if res is not None:
-        r_obj, r_srv = _enforce_resilience(
-            packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
-            capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
-            max_candidates, stats, load, fused, track_rm,
-        )
+        with obs.span("repro.greedy.resilience"):
+            r_obj, r_srv = _enforce_resilience(
+                packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
+                capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
+                max_candidates, stats, load, fused, track_rm,
+            )
         if len(r_obj):
             if engine.scheme is not None:
                 engine.scheme.mask[r_obj, r_srv] = True
@@ -1677,7 +1726,8 @@ def replicate_delta(
         # incremental mirror is what collect_additions=False skipped);
         # sync_host=False defers even this to the caller (streamed
         # ingestion syncs once at stream end)
-        engine.scheme.mask = packed.unpack()
+        with obs.span("repro.greedy.unpack"):
+            engine.scheme.mask = packed.unpack()
         if obs.enabled():
             obs.REGISTRY.counter("repro.greedy.mask_syncs").inc()
 
@@ -1788,14 +1838,17 @@ def replicate_stream(
         if cstats.timeline:
             stats.timeline = (stats.timeline or []) + cstats.timeline
 
-    overlap_s = double_buffer(stream, dispatch)
-    if acc_holder is not None:
-        acc_holder.drain(stats)
+    with obs.span("repro.greedy.stream"):
+        overlap_s = double_buffer(stream, dispatch)
+        if acc_holder is not None:
+            acc_holder.drain(stats)
+        if engine.packed is not None:
+            # the one end-of-stream host sync the per-chunk sync_host=False
+            # deferred (keeps scheme and the engine's host mirror
+            # consistent)
+            with obs.span("repro.greedy.unpack"):
+                scheme.mask = engine.packed.unpack()
     stats.ingest_overlap_s = stream.stats.ingest_overlap_s = overlap_s
-    if engine.packed is not None:
-        # the one end-of-stream host sync the per-chunk sync_host=False
-        # deferred (keeps scheme and the engine's host mirror consistent)
-        scheme.mask = engine.packed.unpack()
     stats.replicas = scheme.replica_count()
     stats.peak_resident_paths = stream.stats.peak_resident_paths
     stream.stats.peak_resident_table_rows = stats.table_peak_rows

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.paths import PAD, PathSet
 from repro.engine import LatencyEngine, pack_bool_mask
 
@@ -348,31 +349,36 @@ def prune_scheme_replicas(
     """
     from repro.core.slo import normalize_path_budgets  # local: no cycle
     from repro.engine import backends as _backends
-    from repro.engine import to_device
+    from repro.engine import to_device, to_host
+    from repro.engine.incremental import PathIndex  # lazy: no cycle
     from repro.engine.routing import resolve_policy
 
     pol = resolve_policy(policy)
-    engine = LatencyEngine(scheme, backend=backend)
-    objects = np.asarray(pathset.objects, np.int32)
-    lengths = np.asarray(pathset.lengths, np.int32)
-    t_path = normalize_path_budgets(t, pathset).astype(np.int64)
-    h0 = np.asarray(
-        engine.path_latencies(pathset, policy=pol, load=load), np.int64
-    )
-    if pathset.n_paths == 0 or np.any(h0 > t_path):
-        return 0, 0.0
-    fv = (
-        np.ones(scheme.n_objects, np.float64)
-        if f is None
-        else np.asarray(f, np.float64)
-    )
+    with obs.span("repro.greedy.prune.pack"):
+        engine = LatencyEngine(scheme, backend=backend)
+        objects = np.asarray(pathset.objects, np.int32)
+        lengths = np.asarray(pathset.lengths, np.int32)
+        t_path = normalize_path_budgets(t, pathset).astype(np.int64)
+        h0 = np.asarray(
+            engine.path_latencies(pathset, policy=pol, load=load), np.int64
+        )
+        if pathset.n_paths == 0 or np.any(h0 > t_path):
+            return 0, 0.0
+        fv = (
+            np.ones(scheme.n_objects, np.float64)
+            if f is None
+            else np.asarray(f, np.float64)
+        )
 
-    # object -> rows of the paths that touch it (built once; same CSR the
-    # engine's incremental dirty-set cache uses)
-    from repro.engine.incremental import PathIndex  # lazy: no cycle
+        # object -> rows of the paths that touch it (built once; same CSR
+        # the engine's incremental dirty-set cache uses)
+        index = PathIndex(objects, scheme.n_objects)
+        affected = index.paths_of
 
-    index = PathIndex(objects, scheme.n_objects)
-    affected = index.paths_of
+        repl = scheme.mask.copy()
+        repl[np.arange(scheme.n_objects), scheme.shard] = False
+        vs, ss = np.nonzero(repl)
+        order = np.argsort(-fv[vs], kind="stable")
 
     L = objects.shape[1]
 
@@ -407,63 +413,60 @@ def prune_scheme_replicas(
                 to_device(o), to_device(ln),
                 engine.packed.words, engine.packed.shard, pol, load=load,
             )
-        return bool(np.all(np.asarray(h)[:P] <= t_path[idx]))
+        return bool(np.all(to_host(h)[:P] <= t_path[idx]))
 
-    repl = scheme.mask.copy()
-    repl[np.arange(scheme.n_objects), scheme.shard] = False
-    vs, ss = np.nonzero(repl)
-    order = np.argsort(-fv[vs], kind="stable")
-    n_dropped = 0
-    bytes_saved = 0.0
+    with obs.span("repro.greedy.prune.sweep"):
+        n_dropped = 0
+        bytes_saved = 0.0
 
-    if fused and backend != "reference" and len(order):
-        rank = _backends._load_vector(
-            load if pol.uses_load else None, engine.packed.words
-        )
-        shard_j = engine.packed.shard
-        for group in _independent_groups(
-            order, vs, affected, pathset.n_paths, group_max
-        ):
-            G = group_max  # fixed group shape -> one jit trace
-            gobj = np.full(G, -1, np.int32)
-            gsrv = np.full(G, -1, np.int32)
-            gobj[: len(group)] = vs[group]
-            gsrv[: len(group)] = ss[group]
-            rows = [affected(int(vs[i])) for i in group]
-            R = max(1, sum(len(r) for r in rows))
-            Rb = -(-R // _PRUNE_ROW_BUCKET) * _PRUNE_ROW_BUCKET
-            robj = np.full((Rb, L), -1, np.int32)
-            rlen = np.zeros(Rb, np.int32)
-            rt = np.zeros(Rb, np.int32)
-            rcand = np.full(Rb, -1, np.int32)
-            at = 0
-            for c, r in enumerate(rows):
-                robj[at : at + len(r)] = objects[r]
-                rlen[at : at + len(r)] = lengths[r]
-                rt[at : at + len(r)] = t_path[r]
-                rcand[at : at + len(r)] = c
-                at += len(r)
-            engine.packed.words, bad = _prune_group_step(
-                engine.packed.words,
-                to_device(gobj), to_device(gsrv),
-                to_device(robj), to_device(rlen), to_device(rt),
-                to_device(rcand),
-                shard_j, rank, pol, backend, G,
+        if fused and backend != "reference" and len(order):
+            rank = _backends._load_vector(
+                load if pol.uses_load else None, engine.packed.words
             )
-            keep = ~np.asarray(bad)[: len(group)]
-            if keep.any():
-                gi = np.asarray(group)[keep]
-                n_dropped += int(keep.sum())
-                bytes_saved += float(fv[vs[gi]].sum())
-                scheme.mask[vs[gi], ss[gi]] = False
-        return n_dropped, bytes_saved
+            shard_j = engine.packed.shard
+            for group in _independent_groups(
+                order, vs, affected, pathset.n_paths, group_max
+            ):
+                G = group_max  # fixed group shape -> one jit trace
+                gobj = np.full(G, -1, np.int32)
+                gsrv = np.full(G, -1, np.int32)
+                gobj[: len(group)] = vs[group]
+                gsrv[: len(group)] = ss[group]
+                rows = [affected(int(vs[i])) for i in group]
+                R = max(1, sum(len(r) for r in rows))
+                Rb = -(-R // _PRUNE_ROW_BUCKET) * _PRUNE_ROW_BUCKET
+                robj = np.full((Rb, L), -1, np.int32)
+                rlen = np.zeros(Rb, np.int32)
+                rt = np.zeros(Rb, np.int32)
+                rcand = np.full(Rb, -1, np.int32)
+                at = 0
+                for c, r in enumerate(rows):
+                    robj[at : at + len(r)] = objects[r]
+                    rlen[at : at + len(r)] = lengths[r]
+                    rt[at : at + len(r)] = t_path[r]
+                    rcand[at : at + len(r)] = c
+                    at += len(r)
+                engine.packed.words, bad = _prune_group_step(
+                    engine.packed.words,
+                    to_device(gobj), to_device(gsrv),
+                    to_device(robj), to_device(rlen), to_device(rt),
+                    to_device(rcand),
+                    shard_j, rank, pol, backend, G,
+                )
+                keep = ~to_host(bad)[: len(group)]
+                if keep.any():
+                    gi = np.asarray(group)[keep]
+                    n_dropped += int(keep.sum())
+                    bytes_saved += float(fv[vs[gi]].sum())
+                    scheme.mask[vs[gi], ss[gi]] = False
+            return n_dropped, bytes_saved
 
-    for i in order:
-        v, s = int(vs[i]), int(ss[i])
-        engine.remove_replicas([v], [s])
-        if subset_ok(affected(v)):
-            n_dropped += 1
-            bytes_saved += float(fv[v])
-        else:
-            engine.add_replicas([v], [s])
-    return n_dropped, bytes_saved
+        for i in order:
+            v, s = int(vs[i]), int(ss[i])
+            engine.remove_replicas([v], [s])
+            if subset_ok(affected(v)):
+                n_dropped += 1
+                bytes_saved += float(fv[v])
+            else:
+                engine.add_replicas([v], [s])
+        return n_dropped, bytes_saved

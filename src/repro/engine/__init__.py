@@ -18,7 +18,8 @@ analyzer, and every benchmark.
                    nearest_copy_dp(k), the suffix-DP lookahead family);
                    consumed by access_trace / path_latencies(policy=)
                    and the policy-aware greedy provisioning gate
-  TRANSFER       — host<->device transfer accounting (perf benchmarks)
+  TRANSFER       — host<->device transfer accounting (perf benchmarks);
+                   ``to_device`` / ``to_host`` are the counted paths
   PathStream     — streamed PathSet ingestion from a host generator with
                    peak-residency accounting (provisioning at scale);
                    consumed by ``repro.core.greedy.replicate_stream``
@@ -58,6 +59,7 @@ from repro.engine.streaming import (
     StreamStats,
     double_buffer,
     to_device,
+    to_host,
 )
 from repro.engine.backends import BACKENDS
 
@@ -72,6 +74,7 @@ __all__ = [
     "unpack_words",
     "TRANSFER",
     "to_device",
+    "to_host",
     "double_buffer",
     "BACKENDS",
     "POLICIES",

@@ -30,7 +30,7 @@ import numpy as np
 from repro.engine import backends
 from repro.engine.packed import PackedScheme, pack_bool_mask
 from repro.engine.routing import resolve_policy
-from repro.engine.streaming import stream_chunks, to_device
+from repro.engine.streaming import stream_chunks, to_device, to_host
 
 DEFAULT_CHUNK = 8192
 
@@ -140,7 +140,7 @@ class LatencyEngine:
 
     def host_shard(self) -> np.ndarray:
         if self.packed is not None:
-            return np.asarray(self.packed.shard)
+            return to_host(self.packed.shard)
         return np.asarray(self.scheme.shard, np.int32)
 
     @property
@@ -297,7 +297,7 @@ class LatencyEngine:
             compute = self._make_policy_compute(pol, load)
         if isinstance(pathset, DevicePaths):
             out = compute(pathset.objects, pathset.lengths)
-            return np.asarray(out)[: pathset.n_paths].astype(np.int32)
+            return to_host(out)[: pathset.n_paths].astype(np.int32)
         n = pathset.n_paths
         outs = stream_chunks(
             [np.asarray(pathset.objects, np.int32), np.asarray(pathset.lengths, np.int32)],
@@ -307,7 +307,7 @@ class LatencyEngine:
             pad_values=[-1, 0],
             align=self.block,
         )
-        host = [np.asarray(o) for o in outs]
+        host = [to_host(o) for o in outs]
         return np.concatenate(host, axis=0)[:n].astype(np.int32)
 
     def _eval_chunk_resident(self, objects, lengths):

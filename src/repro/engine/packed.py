@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.engine.streaming import TRANSFER, to_device
+from repro.engine.streaming import to_device, to_host
 
 
 def n_words(n_servers: int) -> int:
@@ -220,15 +220,14 @@ class PackedScheme:
 
     def unpack(self) -> np.ndarray:
         """Host readback of the full bool mask (one d2h of packed words)."""
-        host = np.asarray(self.words[: self.n_objects])
-        TRANSFER.d2h_bytes += host.nbytes
-        return unpack_words(host, self.n_servers)
+        return unpack_words(to_host(self.words[: self.n_objects]),
+                            self.n_servers)
 
     def storage_per_server(self, f: np.ndarray | None = None) -> np.ndarray:
         n = self.n_objects
         fv = np.ones((n,), np.float32) if f is None else np.asarray(f, np.float32)
         load = _unpack_load_jit(self.words, to_device(fv))
-        return np.asarray(load)[: self.n_servers].astype(np.float64)
+        return to_host(load)[: self.n_servers].astype(np.float64)
 
     def replica_count(self) -> int:
         return int(_popcount_jit(self.words)) - self.n_objects

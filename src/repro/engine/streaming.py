@@ -1,7 +1,10 @@
 """Host<->device transfer accounting + double-buffered chunk streaming.
 
 Every host->device transfer the engine performs goes through ``to_device``
-so the byte counter (``TRANSFER``) reflects real traffic; the perf
+and every blocking device->host readback of the greedy provisioner
+through ``to_host``, so the byte counters (``TRANSFER``) reflect real
+traffic and, with the telemetry plane on, the counter
+``repro.engine.d2h_calls`` counts its host round trips; the perf
 benchmarks (``benchmarks/perf_iterate.py engine`` and
 ``benchmarks/engine_backends.py``) read it to track the packed-resident
 path's transfer advantage over the legacy per-call bool-mask uploads.
@@ -33,6 +36,8 @@ from typing import Callable, Iterable, Iterator, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 
 @dataclasses.dataclass
@@ -106,6 +111,17 @@ def to_device(x, payload_bytes: int | None = None) -> jnp.ndarray:
     TRANSFER.padded_bytes += a.nbytes - payload
     TRANSFER.h2d_calls += 1
     return jnp.asarray(a)
+
+
+def to_host(x) -> np.ndarray:
+    """Counted device->host readback: ``np.asarray(x)``, which blocks until
+    ``x`` is computed, plus its bytes in ``TRANSFER.d2h_bytes`` and, with
+    the telemetry plane on, one ``repro.engine.d2h_calls``."""
+    a = np.asarray(x)
+    TRANSFER.d2h_bytes += a.nbytes
+    if obs.enabled():
+        obs.REGISTRY.counter("repro.engine.d2h_calls").inc()
+    return a
 
 
 def stream_chunks(

@@ -105,5 +105,6 @@ def path_latency_pallas(
         out_specs=pl.BlockSpec((1, block), lambda p: (0, p)),
         out_shape=jax.ShapeDtypeStruct((1, Pp), jnp.int32),
         interpret=interpret,
+        name="repro_path_latency",
     )(home_t, masks_t, lens_t)
     return out[0, :P]

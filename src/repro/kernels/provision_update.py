@@ -222,6 +222,7 @@ def _kernel_rounds(home, wrows, lengths, t, fpos, start, rank, tables,
             jax.ShapeDtypeStruct((1, Bp), jnp.int32),
         ],
         interpret=interpret,
+        name="repro_provision_update",
     )(home_t, to_rows(wrows, block), to_rows(lengths, block),
       to_rows(t, block), to_rows(fpos, block), to_rows(start, block),
       rank, tab, counts.reshape(Hp1, 1))

@@ -163,6 +163,7 @@ def _walk_call(home, masks, lengths, start, score, *, block, interpret,
             jax.ShapeDtypeStruct((L, Pp), jnp.int32),
         ],
         interpret=interpret,
+        name="repro_scored_walk" if scored else "repro_routed_walk",
     )(home_t, to_rows(masks, block), to_rows(lengths, block),
       to_rows(start, block), score)
     return srv.T[:P], loc.T[:P].astype(bool)

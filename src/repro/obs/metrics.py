@@ -193,8 +193,9 @@ class MetricsRegistry:
 
     The registry is only touched at instrument-acquisition time — hot
     loops keep the returned object and mutate it directly.  ``snapshot``
-    returns a plain JSON-serializable dict (the nightly metrics
-    artifact); ``reset`` drops all instruments (tests).
+    returns a plain JSON-serializable dict (the benchmark diffs two of
+    them around a traced window); ``reset`` drops all instruments
+    (tests).
     """
 
     def __init__(self):

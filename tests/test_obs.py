@@ -439,3 +439,170 @@ def test_disabled_plane_registers_nothing(rng):
     assert [n for n in obs.REGISTRY.names()
             if n != "repro.jit.compiles"] == []
     assert stats.timeline is None
+
+
+# ---------------------------------------------------------------------------
+# host spans + the readback counter of the provisioner
+# ---------------------------------------------------------------------------
+PHASES = ("provision", "init", "classes", "revalidate", "unpack", "prune")
+
+
+def test_span_with_the_plane_off_is_a_shared_no_op():
+    obs.disable()
+    obs.REGISTRY.reset()
+    a, b = obs.span("repro.test.a"), obs.span("repro.test.b", paths=3)
+    assert a is b
+    with a, b:
+        pass
+    assert obs.REGISTRY.names() == []
+
+
+def test_span_times_into_the_registry_and_shares_the_call(obs_on):
+    from repro.obs import spans
+
+    with obs.span("repro.test.outer", paths=7) as outer:
+        with obs.span("repro.test.inner") as inner:
+            with obs.span("repro.test.inner") as inner2:
+                pass
+    with obs.span("repro.test.outer") as again:
+        pass
+    assert inner.parent is outer and inner2.parent is inner
+    assert outer.parent is None and again.parent is None
+    # the outer span starts a call; every span nested in it carries its id
+    assert outer.args == {"paths": 7, "call": outer.args["call"]}
+    assert inner.args["call"] == inner2.args["call"] == outer.args["call"]
+    assert again.args["call"] != outer.args["call"]
+    snap = obs_on.snapshot()
+    assert snap["repro.test.outer.n"] == 2
+    assert snap["repro.test.inner.n"] == 2
+    # inclusive: the parent's time holds its children's
+    assert snap["repro.test.outer.ns"] >= snap["repro.test.inner.ns"] > 0
+    assert spans._stack() == []
+
+
+def test_span_closes_on_exception(obs_on):
+    from repro.obs import spans
+
+    with pytest.raises(ValueError):
+        with obs.span("repro.test.boom"):
+            raise ValueError("x")
+    assert obs_on.snapshot()["repro.test.boom.n"] == 1
+    assert spans._stack() == []
+
+
+def test_provision_records_a_span_per_phase(rng, obs_on):
+    ps, shard = random_workload(rng, n_paths=150, n_queries=60)
+    _, stats = replicate_workload(ps, shard, 5, t=1, policy="nearest_copy")
+    assert stats.pruned_replicas > 0  # the prune and its re-pack ran
+    snap = obs_on.snapshot()
+    for phase in PHASES + ("plan", "filter", "batches", "prune.pack",
+                           "prune.sweep", "prune.repack"):
+        assert snap[f"repro.greedy.{phase}.ns"] > 0, phase
+        assert snap[f"repro.greedy.{phase}.n"] >= 1, phase
+    assert snap["repro.greedy.provision.n"] == 1
+    # one span per class, not per batch
+    assert snap["repro.greedy.batches.n"] == snap["repro.greedy.plan.n"]
+    # the phases nest in the call
+    assert snap["repro.greedy.provision.ns"] >= sum(
+        snap[f"repro.greedy.{p}.ns"] for p in PHASES[1:])
+
+
+def test_delta_and_stream_open_their_call_spans(rng, obs_on):
+    from repro.core import replicate_delta, replicate_stream
+    from repro.engine import LatencyEngine
+
+    ps, shard = random_workload(rng, n_paths=80, n_queries=30)
+    replicate_delta(ps, LatencyEngine(ReplicationScheme.from_sharding(
+        shard, 5)), 1, policy="nearest_copy")
+    replicate_stream([ps.select(np.arange(40)),
+                      ps.select(np.arange(40, 80))], shard, 5, t=1)
+    snap = obs_on.snapshot()
+    assert snap["repro.greedy.delta.n"] == 3  # one, then one per chunk
+    assert snap["repro.greedy.stream.n"] == 1
+    assert snap["repro.greedy.revalidate.n"] == 1
+    assert snap["repro.greedy.unpack.n"] == 1  # the stream's end sync
+
+
+@pytest.fixture
+def readbacks(monkeypatch):
+    """Count device->host readbacks made through ``to_host`` and any made
+    elsewhere (``np.asarray`` / ``np.array`` of a device array, or a
+    Python scalar or list taken from one), with the frame that made it."""
+    import sys
+
+    import jax
+    from jax._src.array import ArrayImpl
+
+    from repro.engine import streaming
+
+    seen = {"to_host": 0, "elsewhere": []}
+    to_host_code = streaming.to_host.__code__
+
+    def note(depth):
+        f = sys._getframe(depth)
+        if f.f_code is to_host_code:
+            seen["to_host"] += 1
+            return
+        while f is not None and "repro" not in f.f_code.co_filename:
+            f = f.f_back
+        where = (f"{f.f_code.co_filename}:{f.f_lineno}" if f is not None
+                 else "outside repro")
+        seen["elsewhere"].append(where)
+
+    def wrap(fn):
+        def conv(a, *args, **kw):
+            if isinstance(a, jax.Array):
+                note(2)
+            return fn(a, *args, **kw)
+
+        return conv
+
+    monkeypatch.setattr(np, "asarray", wrap(np.asarray))
+    monkeypatch.setattr(np, "array", wrap(np.array))
+    value = ArrayImpl._value
+
+    def _value(self):
+        note(3)
+        return value.fget(self)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(_value))
+    return seen
+
+
+@pytest.mark.parametrize("case", ["default", "fused", "delta", "resilient"])
+def test_every_provisioning_readback_goes_through_to_host(rng, readbacks,
+                                                          case):
+    from repro.core import replicate_delta
+    from repro.engine import LatencyEngine
+
+    ps, shard = random_workload(rng, n_paths=120, n_queries=50)
+
+    def provision():
+        if case == "delta":
+            eng = LatencyEngine(ReplicationScheme.from_sharding(shard, 5))
+            replicate_delta(ps, eng, 1, policy="nearest_copy")
+            return eng.packed.unpack()
+        scheme, _ = replicate_workload(
+            ps, shard, 5, t=1, policy="nearest_copy",
+            fused=case == "fused",
+            resilience=1 if case == "resilient" else None)
+        return scheme.mask
+
+    was = obs.enabled()
+    obs.disable()
+    try:
+        off = provision()
+        obs.REGISTRY.reset()
+        readbacks["to_host"] = 0
+        readbacks["elsewhere"].clear()
+        obs.enable()
+        on = provision()
+        calls = obs.REGISTRY.snapshot()["repro.engine.d2h_calls"]
+    finally:
+        (obs.enable if was else obs.disable)()
+        obs.REGISTRY.reset()
+    # the plane changes nothing the provisioner computes
+    assert np.array_equal(off, on)
+    assert readbacks["elsewhere"] == []
+    # one count per to_host call, and the unpack the test itself made
+    assert calls == readbacks["to_host"] > 1 + (case == "delta")
