@@ -5,19 +5,19 @@ Two arms build the same scheme on the SNB drift union (``n_servers=6``,
 
   ``separate``  the PR-5 pipeline — per batch, a host-driven routed-gate
                 dispatch, the UPDATE dispatch, and three blocking stat
-                readbacks; then the serial per-candidate prune sweep
-                (~3 dispatches per candidate).
+                readbacks.
   ``fused``     one ``_fused_update_batch`` jit step per batch (gate +
                 candidate scoring + bit-test + scatter-OR in a single
-                dispatch, stats reduced on device) and the batched
-                independent-group prune (~1 dispatch per group).
+                dispatch, stats reduced on device).
 
-Both arms are run twice and the second (warm) run is timed, so the
-comparison excludes jit compilation.  Asserted, not just reported:
+Both arms end with the same batched independent-group prune (one
+dispatch per group).  Both are run twice and the second (warm) run is
+timed, so the comparison excludes jit compilation; the speedup is
+reported, and so is the prune's batching: candidates per gate dispatch,
+counted by the telemetry plane (1 would be a per-candidate sweep).
+Asserted, not just reported:
 
   * the two arms produce **bit-identical** schemes (pre- and post-prune);
-  * fused is >= 5x faster end-to-end (>= 2x under ``--smoke``, where the
-    problem is too small to amortize per-batch overheads);
   * the servers x paths scale grid tops out at ``n_servers=128`` x
     >= 100k synthetic paths provisioned through **streamed ingestion**
     (``replicate_stream``), with peak host-resident paths < the total
@@ -72,8 +72,25 @@ def run_pipeline(union, shard, f, fused: bool):
         union, shard, N_SERVERS, t=T, f=f, policy=POLICY,
         policy_prune=False, fused=fused,
     )
-    prune_scheme_replicas(scheme, union, T, policy=POLICY, f=f, fused=fused)
+    prune_scheme_replicas(scheme, union, T, policy=POLICY, f=f)
     return scheme.mask, time.perf_counter() - t0
+
+
+def prune_batching(union, shard, f):
+    """The prune's (candidates, gate dispatches) in one fused pipeline,
+    from the telemetry plane's counters."""
+    from repro import obs
+
+    names = ("repro.greedy.prune.candidates", "repro.greedy.prune.dispatches")
+    before = obs.REGISTRY.snapshot()
+    was = obs.enabled()
+    obs.enable()
+    try:
+        run_pipeline(union, shard, f, fused=True)
+    finally:
+        (obs.enable if was else obs.disable)()
+    after = obs.REGISTRY.snapshot()
+    return tuple(int(after.get(n, 0) - before.get(n, 0)) for n in names)
 
 
 def default_grid_point():
@@ -154,17 +171,15 @@ def run(out_path: str = "BENCH_scale.json", smoke: bool = False) -> dict:
         "pipeline (schemes must be bit-identical)"
     )
     speedup = arms["separate"][1] / max(arms["fused"][1], 1e-9)
-    floor = 2.0 if smoke else 5.0
-    assert speedup >= floor, (
-        f"fused pipeline speedup {speedup:.2f}x < required {floor}x "
-        f"(separate {arms['separate'][1]:.2f}s, fused {arms['fused'][1]:.2f}s)"
-    )
+    cand, disp = prune_batching(union, shard, f)
     result["snb_union"] = {
         "separate_s": round(arms["separate"][1], 3),
         "fused_s": round(arms["fused"][1], 3),
         "speedup": round(speedup, 2),
-        "speedup_floor": floor,
         "bit_identical": True,
+        "prune_candidates": cand,
+        "prune_dispatches": disp,
+        "prune_candidates_per_dispatch": round(cand / max(disp, 1), 2),
     }
     emit("provisioning_scale", "speedup", round(speedup, 2),
          n_servers=N_SERVERS, paths=union.n_paths)

@@ -1262,9 +1262,8 @@ def replicate_workload(
     eval + UPDATE + per-batch stat readbacks) with one fused jit step per
     batch — gate + candidate scoring + bit-test + scatter-OR in a single
     dispatch, statistics reduced on device (``policy_backend="pallas"``
-    lowers the step to the ``kernels.provision_update`` megakernel) — and
-    prices the final prune sweep with the batched independent-group
-    plan.  Bit-identical to ``fused=False`` by construction (asserted
+    lowers the step to the ``kernels.provision_update`` megakernel).
+    Bit-identical to ``fused=False`` by construction (asserted
     across the full policy x backend matrix in
     tests/test_provision_scale.py).  ``mesh`` (a ``jax.sharding.Mesh``
     from ``repro.engine.sharding.provisioning_mesh``) additionally shards
@@ -1431,7 +1430,6 @@ def replicate_workload(
         with obs.span("repro.greedy.prune"):
             stats.pruned_replicas, _ = prune_scheme_replicas(
                 scheme, pathset, t, policy=pol, f=f_arr, load=load,
-                fused=fused,
             )
             if stats.pruned_replicas:
                 # removals are not monotone: the packed words are stale

@@ -308,7 +308,6 @@ def prune_scheme_replicas(
     policy="nearest_copy",
     f: np.ndarray | None = None,
     backend: str = "jnp",
-    fused: bool = False,
     load: np.ndarray | None = None,
     group_max: int = _PRUNE_GROUP_MAX,
 ) -> tuple[int, float]:
@@ -326,24 +325,20 @@ def prune_scheme_replicas(
 
     The feasibility re-check is *incremental*: a walk only reads the
     replica words of its own path's objects, so removing the copy
-    (v, s) can only change paths that contain ``v`` — each tentative
-    removal clears one membership bit on device
-    (``LatencyEngine.remove_replicas``) and re-walks just the affected
-    paths against their own budgets, instead of re-packing the scheme and
-    re-scanning the workload per candidate (the previous implementation;
-    ~50x slower at benchmark scale).
+    (v, s) can only change paths that contain ``v``, and only those
+    affected paths are re-walked against their own budgets.
 
     One greedy sweep, not an optimal set cover — the measured bytes are
     a lower bound on the over-provisioning.
 
-    ``fused=True`` batches the sweep: candidates whose objects never
-    co-occur on any path are independent (neither decision changes the
-    rows the other's walks read), so each independent group is cleared,
-    re-validated, and selectively restored in ONE jit dispatch
-    (``_prune_group_step``) instead of ~3 per candidate — decision-
-    for-decision identical to the serial sweep by the deferral-closure
-    grouping (see :func:`_independent_groups`).  Falls back to the serial
-    sweep under ``backend="reference"`` (the oracle has no traceable
+    On a device backend (``jnp`` | ``pallas``) the sweep is batched:
+    candidates whose objects never co-occur on any path are independent
+    (neither decision changes the rows the other's walks read), so each
+    independent group is cleared, re-validated, and selectively restored
+    in ONE jit dispatch (``_prune_group_step``) — decision-for-decision
+    identical to the serial sweep by the deferral-closure grouping (see
+    :func:`_independent_groups`).  ``backend="reference"`` runs the
+    serial sweep, one candidate at a time (the oracle has no traceable
     gate).  ``load`` is the forecast per-server load a ``queue_aware``
     policy prices the walks with (ignored by load-blind policies).
     """
@@ -382,91 +377,72 @@ def prune_scheme_replicas(
 
     L = objects.shape[1]
 
-    def subset_ok(idx: np.ndarray) -> bool:
-        """h under the policy for the affected rows, vs their budgets."""
-        if not len(idx):
-            return True
-        if backend == "reference":
-            from repro.core.reference import (
-                routed_path_latencies_reference,
-            )
+    def reference_step(group):
+        """Serial oracle: one candidate, cleared on the host mask."""
+        from repro.core.reference import routed_path_latencies_reference
 
-            h = routed_path_latencies_reference(
-                objects[idx], lengths[idx], scheme.mask, scheme.shard,
-                policy=pol, load=load,
-            )
-            return bool(np.all(h <= t_path[idx]))
-        # pad the row count to a bucket so jit traces stay bounded
-        P = len(idx)
-        Pb = -(-P // 128) * 128
-        o = np.full((Pb, L), -1, np.int32)
-        o[:P] = objects[idx]
-        ln = np.zeros(Pb, np.int32)
-        ln[:P] = lengths[idx]
-        if backend == "pallas":
-            h = _backends.pallas_routed_eval(
-                to_device(o), to_device(ln),
-                engine.packed.words, engine.packed.shard, pol, load=load,
-            )
-        else:
-            h = _backends.routed_counts(
-                to_device(o), to_device(ln),
-                engine.packed.words, engine.packed.shard, pol, load=load,
-            )
-        return bool(np.all(to_host(h)[:P] <= t_path[idx]))
+        (i,) = group
+        rows = affected(int(vs[i]))
+        scheme.mask[vs[i], ss[i]] = False
+        h = routed_path_latencies_reference(
+            objects[rows], lengths[rows], scheme.mask, scheme.shard,
+            policy=pol, load=load,
+        )
+        bad = bool(np.any(h > t_path[rows]))
+        scheme.mask[vs[i], ss[i]] = bad
+        return np.array([bad])
+
+    def device_step(group):
+        """One jit over the group's candidates and their affected rows."""
+        G = group_max  # fixed group shape -> one jit trace
+        gobj = np.full(G, -1, np.int32)
+        gsrv = np.full(G, -1, np.int32)
+        gobj[: len(group)] = vs[group]
+        gsrv[: len(group)] = ss[group]
+        rows = [affected(int(vs[i])) for i in group]
+        R = max(1, sum(len(r) for r in rows))
+        Rb = -(-R // _PRUNE_ROW_BUCKET) * _PRUNE_ROW_BUCKET
+        robj = np.full((Rb, L), -1, np.int32)
+        rlen = np.zeros(Rb, np.int32)
+        rt = np.zeros(Rb, np.int32)
+        rcand = np.full(Rb, -1, np.int32)
+        at = 0
+        for c, r in enumerate(rows):
+            robj[at : at + len(r)] = objects[r]
+            rlen[at : at + len(r)] = lengths[r]
+            rt[at : at + len(r)] = t_path[r]
+            rcand[at : at + len(r)] = c
+            at += len(r)
+        engine.packed.words, bad = _prune_group_step(
+            engine.packed.words,
+            to_device(gobj), to_device(gsrv),
+            to_device(robj), to_device(rlen), to_device(rt),
+            to_device(rcand),
+            engine.packed.shard, rank, pol, backend, G,
+        )
+        return to_host(bad)[: len(group)]
 
     with obs.span("repro.greedy.prune.sweep"):
-        n_dropped = 0
-        bytes_saved = 0.0
-
-        if fused and backend != "reference" and len(order):
+        if backend == "reference":
+            groups, step = [[i] for i in order], reference_step
+        else:
+            groups = _independent_groups(
+                order, vs, affected, pathset.n_paths, group_max
+            )
             rank = _backends._load_vector(
                 load if pol.uses_load else None, engine.packed.words
             )
-            shard_j = engine.packed.shard
-            for group in _independent_groups(
-                order, vs, affected, pathset.n_paths, group_max
-            ):
-                G = group_max  # fixed group shape -> one jit trace
-                gobj = np.full(G, -1, np.int32)
-                gsrv = np.full(G, -1, np.int32)
-                gobj[: len(group)] = vs[group]
-                gsrv[: len(group)] = ss[group]
-                rows = [affected(int(vs[i])) for i in group]
-                R = max(1, sum(len(r) for r in rows))
-                Rb = -(-R // _PRUNE_ROW_BUCKET) * _PRUNE_ROW_BUCKET
-                robj = np.full((Rb, L), -1, np.int32)
-                rlen = np.zeros(Rb, np.int32)
-                rt = np.zeros(Rb, np.int32)
-                rcand = np.full(Rb, -1, np.int32)
-                at = 0
-                for c, r in enumerate(rows):
-                    robj[at : at + len(r)] = objects[r]
-                    rlen[at : at + len(r)] = lengths[r]
-                    rt[at : at + len(r)] = t_path[r]
-                    rcand[at : at + len(r)] = c
-                    at += len(r)
-                engine.packed.words, bad = _prune_group_step(
-                    engine.packed.words,
-                    to_device(gobj), to_device(gsrv),
-                    to_device(robj), to_device(rlen), to_device(rt),
-                    to_device(rcand),
-                    shard_j, rank, pol, backend, G,
-                )
-                keep = ~to_host(bad)[: len(group)]
-                if keep.any():
-                    gi = np.asarray(group)[keep]
-                    n_dropped += int(keep.sum())
-                    bytes_saved += float(fv[vs[gi]].sum())
-                    scheme.mask[vs[gi], ss[gi]] = False
-            return n_dropped, bytes_saved
-
-        for i in order:
-            v, s = int(vs[i]), int(ss[i])
-            engine.remove_replicas([v], [s])
-            if subset_ok(affected(v)):
-                n_dropped += 1
-                bytes_saved += float(fv[v])
-            else:
-                engine.add_replicas([v], [s])
-        return n_dropped, bytes_saved
+            step = device_step
+        drop = np.zeros(len(vs), bool)
+        for group in groups:
+            drop[np.asarray(group, np.int64)[~step(group)]] = True
+        if obs.enabled():
+            obs.REGISTRY.counter("repro.greedy.prune.dispatches").inc(
+                len(groups)
+            )
+            obs.REGISTRY.counter("repro.greedy.prune.candidates").inc(
+                len(order)
+            )
+        gi = order[drop[order]]  # dropped candidates, in serial order
+        scheme.mask[vs[gi], ss[gi]] = False
+        return len(gi), float(fv[vs[gi]].sum())

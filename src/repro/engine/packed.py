@@ -69,30 +69,38 @@ def test_bits(words: jnp.ndarray, objects: jnp.ndarray, servers: jnp.ndarray):
     return ((word >> bit) & jnp.uint32(1)).astype(jnp.bool_)
 
 
+def _scatter_bits(
+    words: jnp.ndarray, objects: jnp.ndarray, servers: jnp.ndarray, on: bool
+) -> jnp.ndarray:
+    """Set (``on``) or clear (object, server) membership bits (traceable).
+
+    Deterministic under duplicate pairs: the update is bit-sliced into 32
+    static rounds; within a round every duplicate write to a cell carries
+    the identical value.  Pairs with a negative object or server — and the
+    sacrificial row itself — are routed to the sacrificial last row, so
+    callers can mask by index instead of compacting.  The rounds scatter
+    into the words flattened to one axis: into the 2-D ``[n + 1, W]``
+    array the TPU compiler re-lays the whole array out (padded to 128
+    lanes) once per round.
+    """
+    pad_row, W = words.shape[0] - 1, words.shape[1]
+    ok = (objects >= 0) & (servers >= 0) & (objects < pad_row)
+    cell = jnp.where(ok, objects * W + servers // 32, pad_row * W).reshape(-1)
+    b_idx = jnp.where(ok, servers % 32, 0).reshape(-1)
+    flat = words.reshape(-1)
+    for b in range(32):
+        i = jnp.where(b_idx == b, cell, pad_row * W)
+        bit = jnp.uint32(1 << b)
+        flat = flat.at[i].set(flat[i] | bit if on else flat[i] & ~bit)
+    return flat.reshape(words.shape)
+
+
 def scatter_or_pairs(
     words: jnp.ndarray, objects: jnp.ndarray, servers: jnp.ndarray
 ) -> jnp.ndarray:
-    """Monotone scatter-OR of (object, server) pairs into the packed words.
-
-    Deterministic under duplicate pairs (OR is idempotent): the update is
-    bit-sliced into 32 static rounds; within a round every duplicate write
-    to a cell carries the identical value.  Pairs with a negative object or
-    server — and the sacrificial row itself — are routed to the sacrificial
-    last row, so callers can mask by index instead of compacting.
-    """
-    pad_row = words.shape[0] - 1
-    ok = (objects >= 0) & (servers >= 0) & (objects < pad_row)
-    obj = jnp.where(ok, objects, pad_row).reshape(-1)
-    srv = jnp.where(ok, servers, 0).reshape(-1)
-    w_idx = srv // 32
-    b_idx = srv % 32
-    for b in range(32):
-        sel = b_idx == b
-        o = jnp.where(sel, obj, pad_row)
-        w = jnp.where(sel, w_idx, 0)
-        old = words[o, w]
-        words = words.at[o, w].set(old | jnp.uint32(1 << b))
-    return words
+    """Monotone scatter-OR of (object, server) pairs into the packed words
+    (see :func:`_scatter_bits`)."""
+    return _scatter_bits(words, objects, servers, True)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -103,26 +111,11 @@ def _scatter_or_jit(words, objects, servers):
 def scatter_clear_pairs(
     words: jnp.ndarray, objects: jnp.ndarray, servers: jnp.ndarray
 ) -> jnp.ndarray:
-    """Clear (object, server) membership bits (the prune-sweep inverse).
-
-    Same masking/bit-slicing discipline as :func:`scatter_or_pairs`:
-    negative pairs are routed to the sacrificial row, duplicates are
-    idempotent.  Removals are NOT monotone — callers that cached derived
-    state (bool masks, engines) must refresh it.
+    """Clear (object, server) membership bits (the prune-sweep inverse; see
+    :func:`_scatter_bits`).  Removals are NOT monotone — callers that
+    cached derived state (bool masks, engines) must refresh it.
     """
-    pad_row = words.shape[0] - 1
-    ok = (objects >= 0) & (servers >= 0) & (objects < pad_row)
-    obj = jnp.where(ok, objects, pad_row).reshape(-1)
-    srv = jnp.where(ok, servers, 0).reshape(-1)
-    w_idx = srv // 32
-    b_idx = srv % 32
-    for b in range(32):
-        sel = b_idx == b
-        o = jnp.where(sel, obj, pad_row)
-        w = jnp.where(sel, w_idx, 0)
-        old = words[o, w]
-        words = words.at[o, w].set(old & ~jnp.uint32(1 << b))
-    return words
+    return _scatter_bits(words, objects, servers, False)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -523,6 +523,53 @@ def test_delta_and_stream_open_their_call_spans(rng, obs_on):
     assert snap["repro.greedy.unpack.n"] == 1  # the stream's end sync
 
 
+@pytest.mark.parametrize("backend", ["jnp", "reference"])
+def test_prune_counts_its_dispatches_and_candidates(rng, monkeypatch,
+                                                    backend):
+    from repro.core import replication
+
+    ps, shard = random_workload(rng, n_paths=150, n_queries=60)
+    scheme, _ = replicate_workload(ps, shard, 5, t=1, policy="nearest_copy",
+                                   policy_prune=False)
+    n_cand = int(scheme.mask.sum()) - len(shard)
+    groups = []
+    grouping = replication._independent_groups
+
+    def spy(*a):
+        groups[:] = grouping(*a)
+        return groups
+
+    monkeypatch.setattr(replication, "_independent_groups", spy)
+
+    def prune():
+        return replication.prune_scheme_replicas(
+            ReplicationScheme(scheme.mask.copy(), shard), ps, 1,
+            policy="nearest_copy", backend=backend, group_max=4)
+
+    was = obs.enabled()
+    try:
+        obs.REGISTRY.reset()
+        obs.enable()
+        on = prune()
+        snap = obs.REGISTRY.snapshot()
+        obs.disable()
+        obs.REGISTRY.reset()
+        off = prune()
+        names = obs.REGISTRY.names()
+    finally:
+        (obs.enable if was else obs.disable)()
+        obs.REGISTRY.reset()
+    assert on == off and on[0] > 0
+    assert snap["repro.greedy.prune.candidates"] == n_cand
+    if backend == "reference":  # the serial oracle: one gate per candidate
+        assert groups == []
+        assert snap["repro.greedy.prune.dispatches"] == n_cand
+    else:  # one dispatch per group; group_max 4 forces several
+        assert 1 < len(groups) < n_cand
+        assert snap["repro.greedy.prune.dispatches"] == len(groups)
+    assert not [n for n in names if n.startswith("repro.greedy.prune")]
+
+
 @pytest.fixture
 def readbacks(monkeypatch):
     """Count device->host readbacks made through ``to_host`` and any made

@@ -7,8 +7,9 @@ Pins the contracts of the fused-megakernel pipeline:
     the same scheme as the PR-5 separate-dispatch pipeline, bit-identically,
     for every routing policy and for both device backends (jnp | pallas);
     total cost matches to float tolerance (f32 accumulation order differs);
-  * **fused prune parity** — the batched independent-group prune makes
-    exactly the serial per-candidate decisions;
+  * **grouped prune parity** — the device prune's independent-group
+    sweep makes exactly the serial reference sweep's decisions, for every
+    routing policy, both device backends and any group size;
   * **transfer accounting** — alignment-pad bytes ride ``padded_bytes``,
     never ``h2d_bytes`` (payload stays exact);
   * **streaming** — ``replicate_stream`` over a chunked ``PathStream``
@@ -103,22 +104,127 @@ def test_fused_reference_backend_downgrades(rng):
 
 
 # ---------------------------------------------------------------------------
-# fused prune: batched independent groups == serial candidate sweep
+# grouped prune: batched independent groups == serial reference sweep
 # ---------------------------------------------------------------------------
-def test_fused_prune_decision_identical(rng):
+def _unpruned(rng, policy, t):
+    """A feasible scheme straight from the greedy, before its prune."""
     ps, shard, n_srv, f = _case(rng)
+    load = (np.array([3.0, 0.0, 1.0, 5.0, 2.0])
+            if policy == "queue_aware" else None)
     scheme, _ = replicate_workload(
-        ps, shard, n_srv, t=1, f=f, policy="nearest_copy",
-        policy_prune=False, fused=True,
+        ps, shard, n_srv, t=t, f=f, policy=policy, load=load,
+        policy_prune=False,
     )
-    serial = ReplicationScheme(scheme.mask.copy(), shard)
-    batched = ReplicationScheme(scheme.mask.copy(), shard)
-    n_s, b_s = (
-        prune_scheme_replicas(s, ps, 1, policy="nearest_copy", f=f, fused=fu)
-        for s, fu in ((serial, False), (batched, True))
+    return ps, shard, f, load, scheme.mask
+
+
+def _pruned(mask, shard, ps, t, policy, f, load, backend, **kw):
+    s = ReplicationScheme(mask.copy(), shard)
+    out = prune_scheme_replicas(
+        s, ps, t, policy=policy, f=f, load=load, backend=backend, **kw
     )
-    assert np.array_equal(serial.mask, batched.mask)
-    assert n_s == b_s  # (dropped, bytes_saved) identical, not just masks
+    return s.mask, out
+
+
+@pytest.mark.parametrize("group_max", [4, 512])
+@pytest.mark.parametrize("t", [1, 2])
+@pytest.mark.parametrize("policy",
+                         ["nearest_copy", "nearest_copy_dp", "queue_aware"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_fused_prune_decision_identical(rng, backend, policy, t, group_max):
+    ps, shard, f, load, mask = _unpruned(rng, policy, t)
+    args = (shard, ps, t, policy, f, load)
+    ref_mask, ref = _pruned(mask, *args, "reference")
+    dev_mask, dev = _pruned(mask, *args, backend, group_max=group_max)
+    assert ref[0] > 0  # the sweep drops replicas: the case is not vacuous
+    assert np.array_equal(ref_mask, dev_mask)
+    assert dev == ref  # (dropped, bytes_saved) identical, not just masks
+
+
+def test_prune_hub_object_takes_a_second_row_bucket(monkeypatch):
+    """An object on more than 1,024 paths pads its group's affected rows
+    to 2,048, and the decisions still equal the serial reference's."""
+    from repro.core import replication
+
+    rng = np.random.default_rng(3)
+    n_obj, n_srv, hub = 60, 4, 0
+    paths = [[int(rng.integers(1, n_obj)), hub, int(rng.integers(1, n_obj))]
+             for _ in range(1100)]
+    ps = PathSet.from_lists(paths)
+    shard = rng.integers(0, n_srv, n_obj).astype(np.int32)
+    shard[hub] = 0
+    scheme, _ = replicate_workload(
+        ps, shard, n_srv, t=1, policy="nearest_copy", policy_prune=False
+    )
+    scheme.mask[hub] = True  # a copy of the hub on every server
+    shapes = []
+    step = replication._prune_group_step
+
+    def spy(words, gobj, gsrv, robj, *a, **kw):
+        shapes.append(robj.shape[0])
+        return step(words, gobj, gsrv, robj, *a, **kw)
+
+    monkeypatch.setattr(replication, "_prune_group_step", spy)
+    ref_mask, ref = _pruned(scheme.mask, shard, ps, 1, "nearest_copy",
+                            None, None, "reference")
+    dev_mask, dev = _pruned(scheme.mask, shard, ps, 1, "nearest_copy",
+                            None, None, "jnp")
+    assert 2048 in shapes
+    assert set(shapes) <= {1024, 2048}
+    assert ref[0] > 0
+    assert np.array_equal(ref_mask, dev_mask)
+    assert dev == ref
+
+
+def _check_groups(groups, order, rows_of, group_max):
+    pos = {int(c): k for k, c in enumerate(order)}
+    flat = [c for g in groups for c in g]
+    assert sorted(flat) == sorted(int(c) for c in order)  # each once
+    group_of = {c: k for k, g in enumerate(groups) for c in g}
+    for g in groups:
+        assert 0 < len(g) <= group_max
+        assert [pos[c] for c in g] == sorted(pos[c] for c in g)
+        rows = np.concatenate([rows_of[c] for c in g])
+        assert len(rows) == len(np.unique(rows))  # no shared row
+    for a in flat:
+        for b in flat:
+            if pos[a] < pos[b] and np.intersect1d(rows_of[a],
+                                                  rows_of[b]).size:
+                # the serially earlier of two dependent candidates is
+                # decided first
+                assert group_of[a] < group_of[b]
+
+
+@pytest.mark.parametrize("group_max", [1, 3, 512])
+def test_independent_groups_keep_the_serial_order(group_max):
+    from repro.core.replication import _independent_groups
+
+    rng = np.random.default_rng(group_max)
+    n_cand, n_paths = 40, 30
+    rows_of = [np.unique(rng.integers(0, n_paths, rng.integers(0, 4)))
+               for _ in range(n_cand)]
+    vs = np.arange(n_cand)
+    order = rng.permutation(n_cand)
+    groups = _independent_groups(
+        order, vs, lambda v: rows_of[v], n_paths, group_max
+    )
+    _check_groups(groups, order, rows_of, group_max)
+    if group_max == 1:
+        assert len(groups) == n_cand
+
+
+def test_deferred_candidate_blocks_later_ones_on_its_rows():
+    """a and b share row 0, b and c share row 1: b is deferred behind a,
+    and c, independent of a, must still wait behind b."""
+    from repro.core.replication import _independent_groups
+
+    rows_of = [np.array([0]), np.array([0, 1]), np.array([1]),
+               np.array([2])]
+    groups = _independent_groups(
+        [0, 1, 2, 3], np.arange(4), lambda v: rows_of[v], 3, 512
+    )
+    assert groups == [[0, 3], [1], [2]]
+    _check_groups(groups, [0, 1, 2, 3], rows_of, 512)
 
 
 # ---------------------------------------------------------------------------

@@ -155,3 +155,34 @@ def test_fused_update_compiles_on_v5e_mesh(one_chip, topo, policy):
     }
     compiled = jax.jit(fn).lower(*(args[n] for n in names)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_prune_group_step_compiles_at_sf1_size(one_chip, monkeypatch,
+                                               backend):
+    """The prune's one dispatch per candidate group, at the shapes the
+    provisioner gives it on LDBC SNB SF1: 3,181,724 objects, 6 servers,
+    paths of 8 objects, a full group of 512 over one 1,024-row bucket.
+    Its bit scatters stay in place: no temporary as large as the words
+    (a 2-D scatter re-lays them out, padded to 128 lanes, every round)."""
+    from repro.core import replication
+    from repro.engine import backends
+
+    monkeypatch.setattr(backends, "interpret_pallas", lambda: False)
+    n_obj, G, Rb, L = 3_181_724, replication._PRUNE_GROUP_MAX, 1024, 8
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = functools.partial(
+        replication._prune_group_step.__wrapped__,
+        pol=resolve_policy("nearest_copy"), backend=backend, G=G)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        spec((n_obj + 1, 1), jnp.uint32),
+        spec((G,), jnp.int32), spec((G,), jnp.int32),
+        spec((Rb, L), jnp.int32), spec((Rb,), jnp.int32),
+        spec((Rb,), jnp.int32), spec((Rb,), jnp.int32),
+        spec((n_obj,), jnp.int32), spec((32,), jnp.float32),
+    ).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n_obj
