@@ -1112,6 +1112,14 @@ def _enforce_resilience(
     latency-feasible under the loss of any single server / fault domain
     combination the constraint names.  Returns the applied (object,
     server) additions.
+
+    With the telemetry plane on, the phase's children are the spans
+    ``repro.greedy.resilience.homes`` (shard readback, failover homes),
+    ``.eval`` (one per round), ``.unpack`` (the host mask of a repair
+    round), ``.repair`` (one per violating case) and ``.replay``; the
+    counters ``.cases``, ``.violations``, ``.orphans`` and ``.additions``
+    count loss-case walks, violating (case, path) pairs, re-homed orphans
+    and the distinct replicas the repairs added.
     """
     from repro.engine.resilience import (  # lazy: no cycle at import
         case_word_mask,
@@ -1119,48 +1127,71 @@ def _enforce_resilience(
     )
 
     n_servers = packed.n_servers
-    shard_host = to_host(packed.shard)
-    cases = res.loss_cases(n_servers)
-    homes = [failover_shard(shard_host, c, n_servers) for c in cases]
+    with obs.span("repro.greedy.resilience.homes"):
+        shard_host = to_host(packed.shard)
+        cases = res.loss_cases(n_servers)
+        homes = [failover_shard(shard_host, c, n_servers) for c in cases]
     W = int(packed.words.shape[1])
     all_obj: list[np.ndarray] = []
     all_srv: list[np.ndarray] = []
     for rnd in range(_RESILIENCE_ROUNDS + 1):
-        h_cases = _resilient_eval(
-            packed, ps, cases, homes, pol, policy_backend, load
-        )
-        viol = h_cases > t_path[None, :]
-        total = int(viol.sum())
+        with obs.span("repro.greedy.resilience.eval", round=rnd):
+            h_cases = _resilient_eval(
+                packed, ps, cases, homes, pol, policy_backend, load
+            )
+            viol = h_cases > t_path[None, :]
+            total = int(viol.sum())
+        if obs.enabled():
+            obs.REGISTRY.counter("repro.greedy.resilience.cases").inc(
+                len(cases))
+            obs.REGISTRY.counter("repro.greedy.resilience.violations").inc(
+                total)
         if total == 0 or rnd == _RESILIENCE_ROUNDS:
             stats.resilient_violations = total
             break
         stats.resilience_rounds += 1
-        mask_host = packed.unpack()
+        with obs.span("repro.greedy.resilience.unpack", round=rnd):
+            mask_host = packed.unpack()
         for d, c in enumerate(cases):
             idx = np.nonzero(viol[d])[0]
             if not len(idx):
                 continue
-            # objects the case orphans: homed on a lost server, no copy
-            # at the rotation failover home yet — re-homed by the repair
-            vobj = np.unique(np.asarray(ps.objects)[idx])
-            vobj = vobj[vobj >= 0]
-            dead = np.zeros(n_servers, bool)
-            dead[np.asarray(c)] = True
-            orphans = vobj[
-                dead[shard_host[vobj]] & ~mask_host[vobj, homes[d][vobj]]
-            ]
-            obj, srv = _repair_loss_case(
-                packed, ps.select(idx), t_path[idx], homes[d],
-                case_word_mask(c, W), orphans, pol, policy_backend,
-                f_arr, f_j, capacity, epsilon, cap_j, eps_j,
-                check_capacity, batch_size, max_candidates, stats, load,
-                fused, track_rm,
-            )
+            with obs.span("repro.greedy.resilience.repair", case=d,
+                          round=rnd):
+                # objects the case orphans: homed on a lost server, no copy
+                # at the rotation failover home yet — re-homed by the repair
+                vobj = np.unique(np.asarray(ps.objects)[idx])
+                vobj = vobj[vobj >= 0]
+                dead = np.zeros(n_servers, bool)
+                dead[np.asarray(c)] = True
+                orphans = vobj[
+                    dead[shard_host[vobj]] & ~mask_host[vobj, homes[d][vobj]]
+                ]
+                obj, srv = _repair_loss_case(
+                    packed, ps.select(idx), t_path[idx], homes[d],
+                    case_word_mask(c, W), orphans, pol, policy_backend,
+                    f_arr, f_j, capacity, epsilon, cap_j, eps_j,
+                    check_capacity, batch_size, max_candidates, stats, load,
+                    fused, track_rm,
+                )
+            if obs.enabled():
+                obs.REGISTRY.counter("repro.greedy.resilience.orphans").inc(
+                    len(orphans))
             if len(obj):
-                # replay into the live scheme: monotone adds, all targets
-                # alive under the case (failover homes by construction)
-                packed.add(obj, srv)
-                mask_host[obj, srv] = True  # keep later cases' orphan filter exact
+                with obs.span("repro.greedy.resilience.replay"):
+                    # replay into the live scheme: monotone adds, all
+                    # targets alive under the case (failover homes by
+                    # construction)
+                    packed.add(obj, srv)
+                    if obs.enabled():
+                        # a batch may choose one pair for several paths
+                        new = np.unique(obj * n_servers + srv)
+                        new = new[~mask_host[new // n_servers,
+                                             new % n_servers]]
+                        obs.REGISTRY.counter(
+                            "repro.greedy.resilience.additions").inc(len(new))
+                    # keep later cases' orphan filter exact
+                    mask_host[obj, srv] = True
                 all_obj.append(obj)
                 all_srv.append(srv)
     return (
@@ -1443,7 +1474,8 @@ def replicate_workload(
                 capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
                 max_candidates, stats, load, fused, track_rm,
             )
-            scheme.mask = packed.unpack()
+            with obs.span("repro.greedy.resilience.unpack"):
+                scheme.mask = packed.unpack()
 
     stats.replicas = scheme.replica_count()
     stats.runtime_s = time.perf_counter() - t0

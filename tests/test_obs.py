@@ -434,6 +434,9 @@ def test_disabled_plane_registers_nothing(rng):
     ps, shard = random_workload(rng, n_paths=60, n_queries=25)
     scheme, stats = replicate_workload(ps, shard, 5, t=2)
     simulate(Cluster(scheme), ps, rate_qps=10_000, seed=1)
+    # the k-resilience gate's spans and counters too
+    replicate_workload(ps, shard, 5, t=1, policy="nearest_copy",
+                       resilience=1)
     # the jit compile hook is a process-global JAX listener (cannot be
     # uninstalled), so its counter may reappear; nothing else may
     assert [n for n in obs.REGISTRY.names()
@@ -521,6 +524,41 @@ def test_delta_and_stream_open_their_call_spans(rng, obs_on):
     assert snap["repro.greedy.stream.n"] == 1
     assert snap["repro.greedy.revalidate.n"] == 1
     assert snap["repro.greedy.unpack.n"] == 1  # the stream's end sync
+
+
+RESILIENCE = ("homes", "eval", "unpack", "repair", "replay")
+
+
+@pytest.mark.parametrize("entry", ["workload", "delta"])
+def test_resilience_spans_tile_the_phase_and_count_its_work(rng, obs_on,
+                                                            entry):
+    from repro.core import replicate_delta
+    from repro.engine import LatencyEngine
+
+    ps, shard = random_workload(rng, n_obj=200, n_srv=6, n_paths=120,
+                                n_queries=50)
+
+    def replicas(resilience):
+        if entry == "delta":
+            eng = LatencyEngine(ReplicationScheme.from_sharding(shard, 6))
+            return replicate_delta(ps, eng, 1, policy="nearest_copy",
+                                   resilience=resilience)[0].replicas
+        return replicate_workload(ps, shard, 6, t=1, policy="nearest_copy",
+                                  resilience=resilience)[1].replicas
+
+    before = replicas(None)
+    obs_on.reset()
+    after = replicas(1)
+    snap = obs_on.snapshot()
+    phase = snap["repro.greedy.resilience.ns"]
+    children = sum(snap.get(f"repro.greedy.resilience.{c}.ns", 0)
+                   for c in RESILIENCE)
+    assert snap["repro.greedy.resilience.repair.n"] >= 1
+    assert 0.97 * phase <= children <= phase
+    assert snap["repro.greedy.resilience.cases"] == (
+        6 * snap["repro.greedy.resilience.eval.n"])
+    assert snap["repro.greedy.resilience.violations"] > 0
+    assert snap["repro.greedy.resilience.additions"] == after - before > 0
 
 
 @pytest.mark.parametrize("backend", ["jnp", "reference"])

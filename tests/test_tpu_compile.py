@@ -186,3 +186,65 @@ def test_prune_group_step_compiles_at_sf1_size(one_chip, monkeypatch,
     ).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n_obj
+
+
+# The k-resilience gate at the shapes LDBC SNB SF1 gives it: 3,181,725
+# objects (one word, 6 servers), D = 6 single-server loss cases, a call's
+# 3,072 paths of 8 objects.
+SF1_OBJ, SF1_CASES, SF1_PATHS = 3_181_725, 6, 3072
+
+
+@pytest.mark.parametrize("walk", ["routed", "home"])
+def test_resilient_walk_compiles_at_sf1_size(one_chip, walk):
+    """The masked re-walk of every loss case in one vmapped dispatch
+    (``nearest_copy`` and ``home_first``).  Its temporaries hold the D
+    masked copies of the words at most: none is re-laid out with 128
+    lanes of padding."""
+    from repro.engine import backends
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [spec((SF1_PATHS, 8), jnp.int32), spec((SF1_PATHS,), jnp.int32),
+            spec((SF1_OBJ + 1, 1), jnp.uint32),
+            spec((SF1_CASES, 1), jnp.uint32),
+            spec((SF1_CASES, SF1_OBJ), jnp.int32)]
+    if walk == "routed":
+        fn = functools.partial(backends._resilient_routed_vmap.__wrapped__,
+                               lookahead=True)
+        args.append(spec((32,), jnp.float32))
+    else:
+        fn = backends._resilient_home_vmap.__wrapped__
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4 * SF1_OBJ * (SF1_CASES + 4))
+
+
+def test_masked_update_compiles_at_sf1_size(one_chip):
+    """One loss case's words masked (``mask_case_words``), then the UPDATE
+    step of a 256-path batch on them, as the repair of a violating case
+    runs it: no temporary as large as the words."""
+    from repro.core import greedy
+    from repro.engine.backends import mask_case_words
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, L, S = 256, 8, 6
+    tables, counts = combi.stacked_tables(L - 1, 1)
+
+    def masked_update(words, cmask, *rest):
+        return greedy._update_batch_core(
+            mask_case_words(words, cmask), *rest,
+            check_capacity=False, routed_gate=True)
+
+    compiled = jax.jit(masked_update).lower(
+        spec((SF1_OBJ + 1, 1), jnp.uint32), spec((1,), jnp.uint32),
+        spec((B, L), jnp.int32), spec((B,), jnp.int32),
+        spec((SF1_OBJ,), jnp.int32), spec((SF1_OBJ,), jnp.float32),
+        spec(tables.shape, jnp.bool_), spec(counts.shape, jnp.int32),
+        spec((B,), jnp.int32), spec((B,), jnp.int32),
+        spec((S,), jnp.float32), spec((S,), jnp.float32),
+        spec((), jnp.float32),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * SF1_OBJ
