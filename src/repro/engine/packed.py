@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.engine.streaming import to_device, to_host
 
 
@@ -37,22 +38,36 @@ def n_words(n_servers: int) -> int:
 
 
 def pack_bool_mask(mask: np.ndarray) -> np.ndarray:
-    """Host-side pack: bool [R, S] -> uint32 [R, ceil(S/32)]."""
-    R, S = mask.shape
-    W = n_words(S)
-    padded = np.zeros((R, W * 32), dtype=bool)
-    padded[:, :S] = mask
-    bits = padded.reshape(R, W, 32).astype(np.uint32)
-    weights = (np.uint32(1) << np.arange(32, dtype=np.uint32))[None, None, :]
-    return (bits * weights).sum(axis=2).astype(np.uint32)
+    """Host-side pack: bool [R, S] -> uint32 [R, ceil(S/32)].
+
+    Byte-wise: each row's bits go into ``ceil(S/8)`` little-endian bytes
+    (``np.packbits``), placed at the front of the row's ``4 * W`` bytes
+    and read as ``'<u4'`` words, so server ``s`` is bit ``s % 32`` of
+    word ``s // 32`` on any host.  Accepts any array ``np.asarray`` makes
+    bool (non-contiguous slices, 0/1 integers, no rows).
+    """
+    m = np.asarray(mask, dtype=bool)
+    R, S = m.shape
+    with obs.span("repro.engine.codec", op="pack", rows=R):
+        W, B = n_words(S), -(-S // 8)
+        if S != 8 * B:  # pad each row to whole bytes: one flat pack, not R
+            padded = np.zeros((R, 8 * B), dtype=bool)
+            padded[:, :S] = m
+            m = padded
+        packed = np.packbits(m.reshape(-1), bitorder="little").reshape(R, B)
+        out = np.zeros((R, 4 * W), dtype=np.uint8)
+        out[:, :B] = packed
+        return out.view("<u4")
 
 
 def unpack_words(words: np.ndarray, n_servers: int) -> np.ndarray:
-    """Host-side unpack: uint32 [R, W] -> bool [R, n_servers]."""
+    """Host-side unpack: uint32 [R, W] -> bool [R, n_servers] (a new,
+    writable, C-contiguous array; the inverse of :func:`pack_bool_mask`)."""
     R, W = words.shape
-    shifts = np.arange(32, dtype=np.uint32)
-    bits = (words[:, :, None] >> shifts[None, None, :]) & np.uint32(1)
-    return bits.reshape(R, W * 32)[:, :n_servers].astype(bool)
+    with obs.span("repro.engine.codec", op="unpack", rows=R):
+        b = np.ascontiguousarray(words, dtype="<u4").view(np.uint8)
+        return np.unpackbits(b.reshape(R, 4 * W), axis=1, count=n_servers,
+                             bitorder="little").view(bool)
 
 
 # ---------------------------------------------------------------------------
@@ -212,8 +227,9 @@ class PackedScheme:
         )
 
     def unpack(self) -> np.ndarray:
-        """Host readback of the full bool mask (one d2h of packed words)."""
-        return unpack_words(to_host(self.words[: self.n_objects]),
+        """Host readback of the full bool mask: one d2h of the whole words,
+        the sacrificial row dropped on the host (it may hold set bits)."""
+        return unpack_words(to_host(self.words)[: self.n_objects],
                             self.n_servers)
 
     def storage_per_server(self, f: np.ndarray | None = None) -> np.ndarray:

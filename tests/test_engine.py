@@ -95,9 +95,69 @@ def test_packed_roundtrip_and_scatter(rng):
     assert np.array_equal(packed.unpack(), want)
 
 
-def test_pack_unpack_inverse(rng):
-    mask = rng.random((33, 70)) < 0.5
-    assert np.array_equal(unpack_words(pack_bool_mask(mask), 70), mask)
+def _pack_oracle(mask):
+    """The 32-lane formula: each row widened to 32 uint32 lanes a word."""
+    R, S = mask.shape
+    W = (S + 31) // 32
+    padded = np.zeros((R, W * 32), dtype=bool)
+    padded[:, :S] = mask
+    bits = padded.reshape(R, W, 32).astype(np.uint32)
+    weights = (np.uint32(1) << np.arange(32, dtype=np.uint32))[None, None, :]
+    return (bits * weights).sum(axis=2).astype(np.uint32)
+
+
+def _unpack_oracle(words, n_servers):
+    R, W = words.shape
+    shifts = np.arange(32, dtype=np.uint32)
+    bits = (words[:, :, None] >> shifts[None, None, :]) & np.uint32(1)
+    return bits.reshape(R, W * 32)[:, :n_servers].astype(bool)
+
+
+@pytest.mark.parametrize("rows", [0, 1, 1000])
+@pytest.mark.parametrize("n_srv", [1, 6, 8, 9, 31, 32, 33, 64, 70])
+def test_pack_unpack_inverse(rng, n_srv, rows):
+    mask = rng.random((rows, n_srv)) < 0.5
+    wide = rng.random((rows, 2 * n_srv + 1)) < 0.5
+    wide[:, 1::2] = mask  # a non-contiguous column slice that equals mask
+    for given in (mask, wide[:, 1::2], mask.astype(np.uint8)):
+        words = pack_bool_mask(given)
+        assert words.shape == (rows, (n_srv + 31) // 32)
+        assert words.dtype == np.uint32
+        assert np.array_equal(words, _pack_oracle(mask))
+        for s in range(n_srv):  # bit s % 32 of word s // 32, and only there
+            bit = (words[:, s // 32] >> np.uint32(s % 32)) & np.uint32(1)
+            assert np.array_equal(bit.astype(bool), mask[:, s]), s
+        back = unpack_words(words, n_srv)
+        assert np.array_equal(back, mask)
+        assert np.array_equal(back, _unpack_oracle(words, n_srv))
+        assert back.dtype == bool
+        assert back.flags.c_contiguous and back.flags.writeable
+        back[:, :] = True  # owned: writing leaves the words alone
+        assert np.array_equal(words, _pack_oracle(mask))
+
+
+def test_unpack_drops_the_sacrificial_row_on_the_host(rng):
+    from repro import obs
+
+    mask = rng.random((50, 6)) < 0.3
+    packed = PackedScheme.from_mask(mask, np.zeros(50, np.int32))
+    # a negative object lands in the sacrificial row and sets its bit
+    packed.add([-1, 7], [4, 2])
+    assert np.asarray(packed.words)[-1].any()
+    mask[7, 2] = True
+    was = obs.enabled()
+    obs.REGISTRY.reset()
+    obs.enable()
+    try:
+        got = packed.unpack()
+        snap = obs.REGISTRY.snapshot()
+    finally:
+        (obs.enable if was else obs.disable)()
+        obs.REGISTRY.reset()
+    assert got.shape == (50, 6)
+    assert np.array_equal(got, mask)
+    assert snap["repro.engine.d2h_calls"] == 1
+    assert snap["repro.engine.codec.n"] == 1
 
 
 def test_margin_costs_against_snapshot(rng):

@@ -526,6 +526,21 @@ def test_delta_and_stream_open_their_call_spans(rng, obs_on):
     assert snap["repro.greedy.unpack.n"] == 1  # the stream's end sync
 
 
+@pytest.mark.parametrize("policy,passes", [("home_first", 1),
+                                            ("nearest_copy", 3)])
+def test_provision_counts_its_codec_passes(rng, obs_on, policy, passes):
+    """A call's whole-mask encodes and decodes: home_first decodes once;
+    nearest_copy decodes, then its prune packs the mask into an engine
+    and re-packs what it kept."""
+    ps, shard = random_workload(rng, n_paths=150, n_queries=60)
+    _, stats = replicate_workload(ps, shard, 5, t=1, policy=policy)
+    assert stats.fallback_paths == 0
+    assert (stats.pruned_replicas > 0) == (policy == "nearest_copy")
+    snap = obs_on.snapshot()
+    assert snap["repro.engine.codec.n"] == passes
+    assert snap["repro.engine.codec.ns"] > 0
+
+
 RESILIENCE = ("homes", "eval", "unpack", "repair", "replay")
 
 
