@@ -34,6 +34,7 @@ to one class, bit-identical to the historical scalar driver.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 import time
@@ -368,7 +369,7 @@ class DeviceStatsAcc:
     The fused UPDATE accumulates (cost, failed, skipped) in a device
     f32[3]; reading it back per class blocks dispatch and breaks the
     streamed-ingestion pipeline.  Holding the accumulator here instead
-    carries it across :func:`replicate_delta` calls — chunk ``i + 1``'s
+    carries it across the streamed chunks' delta passes — chunk ``i + 1``'s
     host work proceeds while chunk ``i`` still computes — and
     :meth:`drain` performs the one blocking readback at stream end.
     While deferred, per-chunk stats report these components as 0; the
@@ -412,72 +413,53 @@ def _obs_record_class(stats, b, n_vec, n_seq, counts, n_skip) -> None:
 
 
 def _run_update_batches(
-    packed: PackedScheme,
+    p: _Pass,
     vec_objects: np.ndarray,
     vec_lengths: np.ndarray,
-    shard_j,
-    f_arr: np.ndarray,
-    f_j,
     tables,
     counts,
     t_vec: np.ndarray,
-    load,
-    cap_j,
-    eps_j,
-    check_capacity: bool,
-    batch_size: int,
-    stats: GreedyStats,
-    track_rm: bool,
-    collect_additions: bool = False,
-    routed_fn=None,
-    fused: bool = False,
-    pol=None,
-    rank=None,
-    use_pallas: bool = False,
-    mesh=None,
-    acc_holder: DeviceStatsAcc | None = None,
-):
-    """The batched UPDATE loop over vectorizable paths (shared by the
-    from-scratch driver and the incremental delta driver).
+) -> None:
+    """The batched UPDATE loop of one budget class over the pass ``p``.
 
     ``t_vec`` is the int32 per-path budget vector (one entry per row of
     ``vec_objects``); the candidate ``tables`` must have been enumerated
-    for these budgets (one budget class per call — see the drivers).
+    for these budgets (one budget class per call — see :meth:`_Pass.run`).
 
-    ``routed_fn`` (policy-aware greedy, separate-dispatch path) maps a
-    host (objects, lengths) batch to its routed path latencies against
-    the *current* packed snapshot; paths within budget under the routed
-    walk are gated out of the UPDATE (they buy nothing), re-checked per
-    batch so mid-class additions keep shrinking the bill.
+    Separate dispatch (the default): the pass's routed gate maps each
+    host batch to its routed path latencies against the *current* packed
+    snapshot — paths within budget under the routed walk are gated out of
+    the UPDATE (they buy nothing), re-checked per batch so mid-class
+    additions keep shrinking the bill — then ``_update_batch`` runs and
+    its three stats are read back.
 
-    ``fused`` replaces the per-batch (host gate dispatch -> UPDATE
-    dispatch -> three blocking stat readbacks) round trip with one
-    ``_fused_update_batch`` step per batch: the gate runs inside the same
-    jit (``pol`` + ``rank``), stats accumulate in a device vector read
-    once at the end, and ``use_pallas`` lowers the round to the
-    ``kernels.provision_update`` megakernel.  ``mesh`` (fused only)
-    uploads every batch path-sharded across its devices.  ``acc_holder``
-    defers even that one end-of-call readback: the device stat vector is
-    carried in the holder across calls (streamed ingestion) and drained
-    once by the caller — the stat components stay 0 in ``stats`` until
-    then.
+    ``p.fused`` replaces that round trip with one ``_fused_update_batch``
+    step per batch: the gate runs inside the same jit (``p.pol`` +
+    ``p.rank``), stats accumulate in a device vector read once at the
+    end, and ``p.use_pallas`` lowers the round to the
+    ``kernels.provision_update`` megakernel.  ``p.mesh`` (fused only)
+    uploads every batch path-sharded across its devices.
+    ``p.acc_holder`` defers even that one end-of-class readback: the
+    device stat vector is carried in the holder across calls (streamed
+    ingestion) and drained once by the caller — the stat components stay
+    0 in ``p.stats`` until then.
 
-    Mutates ``packed`` (donated words) and ``stats``; returns the final
-    device load and, when ``collect_additions``, the applied (object,
-    server) pairs as two int64 arrays.
+    Mutates ``p.packed`` (donated words), ``p.srv_load`` and ``p.stats``;
+    with ``p.collect`` the applied (object, server) pairs join
+    ``p.added``.
     """
     add_obj: list[np.ndarray] = []
     add_srv: list[np.ndarray] = []
+    packed, stats, batch_size = p.packed, p.stats, p.batch_size
     nb = len(vec_objects)
-    put = to_device
-    if fused:
-        if acc_holder is not None and acc_holder.acc is not None:
-            acc = acc_holder.acc
+    put, rank, load = to_device, p.rank, p.srv_load
+    if p.fused:
+        holder = p.acc_holder
+        if holder is not None and holder.acc is not None:
+            acc = holder.acc
         else:
             acc = jnp.zeros((3,), jnp.float32)
-        if rank is None:
-            rank = jnp.zeros((packed.words.shape[1] * 32,), jnp.float32)
-    if mesh is not None:
+    if p.mesh is not None:
         from repro.engine import sharding as _sharding
 
         # the words and load are replicated on the mesh for this loop
@@ -485,11 +467,11 @@ def _run_update_batches(
         # run Pallas kernels on one device (GSPMD cannot partition a
         # Mosaic kernel), and a later budget class arrives with both
         # back on one device
-        put = _sharding.batch_put(mesh)
-        packed.words = _sharding.replicate(packed.words, mesh)
-        rank = _sharding.replicate(rank, mesh)
-        load = _sharding.replicate(load, mesh)
-        acc = _sharding.replicate(acc, mesh)
+        put = _sharding.batch_put(p.mesh)
+        packed.words = _sharding.replicate(packed.words, p.mesh)
+        rank = _sharding.replicate(rank, p.mesh)
+        load = _sharding.replicate(load, p.mesh)
+        acc = _sharding.replicate(acc, p.mesh)
     for i in range(0, nb, batch_size):
         o = vec_objects[i : i + batch_size]
         l = vec_lengths[i : i + batch_size]
@@ -503,75 +485,75 @@ def _run_update_batches(
             l = np.concatenate([l, np.zeros((padn,), np.int32)])
             tq = np.concatenate([tq, np.zeros((padn,), np.int32)])
         k = min(batch_size, nb - i)
-        if fused:
+        if p.fused:
             packed.words, acc, load, chosen, first_obj, srv = _fused_update_batch(
                 packed.words,
                 acc,
                 put(o, payload_bytes=pb_o),
                 put(l, payload_bytes=pb_l),
-                shard_j,
-                f_j,
+                packed.shard,
+                p.f_j,
                 tables,
                 counts,
                 put(tq, payload_bytes=pb_t),
                 rank,
                 load,
-                cap_j,
-                eps_j,
-                check_capacity,
-                pol,
-                use_pallas,
-                mesh,
+                p.cap_j,
+                p.eps_j,
+                p.check_capacity,
+                p.pol,
+                p.use_pallas,
+                p.mesh,
             )
         else:
-            if routed_fn is not None:
+            if p.routed_fn is not None:
                 # routed latency against the snapshot the batch prices on
-                h_rt = np.asarray(routed_fn(o, l), np.int32)
+                h_rt = np.asarray(p.routed_fn(o, l), np.int32)
             else:
                 h_rt = np.zeros_like(tq)
             packed.words, costs, failed, chosen, first_obj, srv, load, skipped = _update_batch(
                 packed.words,
                 to_device(o, payload_bytes=pb_o),
                 to_device(l, payload_bytes=pb_l),
-                shard_j,
-                f_j,
+                packed.shard,
+                p.f_j,
                 tables,
                 counts,
                 to_device(tq, payload_bytes=pb_t),
                 to_device(h_rt, payload_bytes=h_rt[:k].nbytes),
                 load,
-                cap_j,
-                eps_j,
-                check_capacity,
-                routed_fn is not None,
+                p.cap_j,
+                p.eps_j,
+                p.check_capacity,
+                p.routed_fn is not None,
             )
             stats.total_cost += float(to_host(costs)[:k].sum())
             stats.failed_paths += int(to_host(failed)[:k].sum())
             stats.routed_skips += int(to_host(skipped)[:k].sum())
-        if check_capacity:
+        if p.check_capacity:
             # exact load from the packed words, computed on device (the
             # incremental estimate can over-count duplicate additions
             # within a batch) — no host round trip of the mask.
             load = jnp.asarray(
-                packed.storage_per_server(f_arr).astype(np.float32)
+                packed.storage_per_server(p.f_arr).astype(np.float32)
             )
-        if track_rm or collect_additions:
+        if p.track_rm or p.collect:
             ch = to_host(chosen)[:k]
             sv = to_host(srv)[:k]
             bb, xx, kk = np.nonzero(ch)
-            if collect_additions:
+            if p.collect:
                 add_obj.append(o[bb, xx].astype(np.int64))
                 add_srv.append(sv[bb, kk].astype(np.int64))
-            if track_rm:
+            if p.track_rm:
                 fo = to_host(first_obj)[:k]
                 for b, x, kk_ in zip(bb, xx, kk):
                     stats.rm.append(
                         (int(fo[b, kk_]), int(o[b, x]), int(sv[b, kk_]))
                     )
-    if fused:
-        if acc_holder is not None:
+    if p.fused:
+        if p.acc_holder is not None:
             # deferred: keep the stats on device, drained at stream end
-            acc_holder.acc = acc
+            p.acc_holder.acc = acc
         else:
             # one device->host readback for the whole class (pad rows are
             # inert in every component, see _fused_update_batch)
@@ -581,18 +563,12 @@ def _run_update_batches(
             stats.routed_skips += int(a[2])
             if obs.enabled():
                 obs.REGISTRY.counter("repro.greedy.stat_readbacks").inc()
-    if mesh is not None:
-        packed.words = _sharding.one_device(packed.words, mesh)
-        load = _sharding.one_device(load, mesh)
-    additions = (
-        (
-            np.concatenate(add_obj) if add_obj else np.zeros(0, np.int64),
-            np.concatenate(add_srv) if add_srv else np.zeros(0, np.int64),
-        )
-        if collect_additions
-        else None
-    )
-    return load, additions
+    if p.mesh is not None:
+        packed.words = _sharding.one_device(packed.words, p.mesh)
+        load = _sharding.one_device(load, p.mesh)
+    p.srv_load = load
+    if add_obj:
+        p.added.append((np.concatenate(add_obj), np.concatenate(add_srv)))
 
 
 # host-residency bound on candidate-table construction: a budget class
@@ -714,7 +690,7 @@ def _routed_eval_rows(routed_fn, ps, rows: np.ndarray) -> np.ndarray:
     return np.asarray(routed_fn(o, ln), np.int64)[:D]
 
 
-def _revalidate_routed(routed_fn, ps, t_path, run_classes, stats,
+def _revalidate_routed(routed_fn, ps, t_path, run, stats,
                        index=None) -> None:
     """Bounded re-validation after a policy-aware pass.
 
@@ -737,7 +713,7 @@ def _revalidate_routed(routed_fn, ps, t_path, run_classes, stats,
     for _ in range(_POLICY_REVALIDATE):
         if not len(viol):
             break
-        run_classes(ps.select(viol), t_path[viol])
+        run(ps.select(viol), t_path[viol])
         if index is not None:
             cand = index.dirty_paths(np.asarray(ps.objects)[viol])
             stats.revalidate_rows_saved += int(ps.n_paths - len(cand))
@@ -848,36 +824,6 @@ def _routed_class_filter(
     return vec_idx, seq_idx, tables, counts, n_skipped
 
 
-def _fused_setup(packed: PackedScheme, pol, load, fused: bool, mesh,
-                 batch_size: int):
-    """Shared fused-driver preamble: the gate holder-rank vector and the
-    batch size rounded to a device-count multiple."""
-    if not fused:
-        if mesh is not None:
-            raise ValueError("mesh= requires fused=True")
-        return None, batch_size
-    from repro.engine.backends import _load_vector  # lazy: no cycle at import
-
-    rank = _load_vector(
-        load if (pol is not None and pol.uses_load) else None, packed.words
-    )
-    if mesh is not None:
-        nd = int(np.prod(list(mesh.shape.values())))
-        batch_size = -(-batch_size // nd) * nd
-    return rank, batch_size
-
-
-def _capacity_arrays(n_servers: int, capacity, epsilon):
-    check = capacity is not None or epsilon is not None
-    cap_arr = np.full((n_servers,), np.inf, np.float32)
-    if capacity is not None:
-        cap_arr = np.broadcast_to(
-            np.asarray(capacity, np.float32), (n_servers,)
-        ).copy()
-    eps = np.float32(epsilon if epsilon is not None else np.inf)
-    return check, jnp.asarray(cap_arr), jnp.asarray(eps)
-
-
 # routed-feasibility re-validation rounds after a policy-aware pass: the
 # receding-horizon walks are not strictly monotone under foreign replica
 # additions, so a path gated out early is re-checked against the final
@@ -942,165 +888,196 @@ def _resilient_eval(packed: PackedScheme, ps: PathSet, cases, homes,
     return to_host(out).astype(np.int64)
 
 
-def _repair_loss_case(
-    packed: PackedScheme,
-    sub_ps: PathSet,
-    t_sub: np.ndarray,
-    fshard: np.ndarray,
-    cmask_words: np.ndarray,
-    orphans: np.ndarray,
-    pol,
-    policy_backend: str,
-    f_arr: np.ndarray,
-    f_j,
-    capacity,
-    epsilon,
-    cap_j,
-    eps_j,
-    check_capacity: bool,
-    batch_size: int,
-    max_candidates: int,
-    stats: GreedyStats,
-    load,
-    fused: bool,
-    track_rm: bool,
-):
-    """One masked UPDATE pass: provision ``sub_ps`` as if the loss case
-    had already happened.
+class _Pass:
+    """One UPDATE pass: what the batched UPDATE, its routed gate and its
+    exact fallback share, built once per driver call (and once per
+    loss-case repair, :meth:`masked`).
 
-    Builds a temporary :class:`PackedScheme` view — the live words with
-    the lost servers' holder bits cleared, sharded by the case's
-    rotation-failover homes — and runs the same batched UPDATE machinery
-    (routed gate included) against it.  ``orphans`` are the violating
-    paths' objects whose home the case took down and whose failover home
-    holds no copy yet: they are **re-homed first** (a copy provisioned at
-    the rotation target), because the UPDATE's closed-form cost model
-    prices every object as free at its own home — an assumption the
-    masked scheme breaks exactly at the orphans (and the assumption a
-    real system restores by resharding off a dead server; re-homing is
-    also what makes the data itself survive the case).  Every candidate
-    server is a failover home, hence alive under the case by
-    construction; capacity is checked on the masked load, which equals
-    the live load on every surviving server.  Returns the applied
-    (object, server) additions — orphan re-homes included — for the
-    caller to replay into the live scheme (Thm 5.3: replaying them can
-    only lower latencies of the unmasked walk too).
+    :meth:`run` provisions a path set into ``packed``: budget-class plan
+    -> routed class filter -> batched UPDATE -> exact ``update_exact``
+    fallback, replayed into the words.  The fallback prices against
+    ``host_view()``, the caller's host scheme of the words: the
+    workload's, refreshed from the words; the delta engine's host mirror;
+    a repair's unpack of its masked words.  With ``collect`` the applied
+    (object, server) pairs are kept for :meth:`additions`;
+    ``acc_holder`` defers the fused stat readback to the caller (streamed
+    ingestion).  ``srv_load`` is the live per-server storage when the
+    caller already holds it on the host; otherwise it is read off the
+    words.
     """
-    from repro.engine.backends import mask_case_words  # lazy: no cycle
 
-    masked = PackedScheme(
-        words=mask_case_words(packed.words, to_device(cmask_words)),
-        shard=to_device(np.asarray(fshard, np.int32)),
-        n_servers=packed.n_servers,
-    )
-    if len(orphans):
-        masked.add(orphans, np.asarray(fshard)[orphans])
-    routed_fn = _routed_gate_fn(masked, pol, policy_backend, load=load)
-    fused_c = fused and policy_backend != "reference"
-    use_pallas = fused_c and policy_backend == "pallas"
-    rank, bsz = _fused_setup(masked, pol, load, fused_c, None, batch_size)
-    srv_load = jnp.asarray(masked.storage_per_server(f_arr).astype(np.float32))
-    host_scheme: ReplicationScheme | None = None
-    add_obj: list[np.ndarray] = []
-    add_srv: list[np.ndarray] = []
-    if len(orphans):
-        add_obj.append(np.asarray(orphans, np.int64))
-        add_srv.append(np.asarray(fshard, np.int64)[orphans])
-    for b, cls, vec_idx, seq_idx, h_all, tables, counts in _budget_class_plan(
-        sub_ps, t_sub, masked.shard, max_candidates,
-        skip_tables=routed_fn is not None, stats=stats,
-    ):
-        if routed_fn is not None and cls.n_paths:
-            vec_idx, seq_idx, tables, counts, n_skip = _routed_class_filter(
-                cls, b, h_all, routed_fn, max_candidates, stats=stats
-            )
-            stats.routed_skips += n_skip
-        srv_load, additions = _run_update_batches(
-            masked,
-            cls.objects[vec_idx],
-            cls.lengths[vec_idx],
-            masked.shard,
-            f_arr,
-            f_j,
-            tables,
-            counts,
-            np.full(len(vec_idx), b, np.int32),
-            srv_load,
-            cap_j,
-            eps_j,
-            check_capacity,
-            bsz,
-            stats,
-            track_rm,
-            collect_additions=True,
-            routed_fn=None if fused_c else routed_fn,
-            fused=fused_c,
-            pol=pol,
-            rank=rank,
-            use_pallas=use_pallas,
+    def __init__(self, packed: PackedScheme, f_arr: np.ndarray, capacity,
+                 epsilon, pol, backend: str, load, fused: bool, mesh,
+                 batch_size: int, max_candidates: int, stats: GreedyStats,
+                 track_rm: bool, host_view, collect: bool = False,
+                 acc_holder: DeviceStatsAcc | None = None, srv_load=None):
+        n_servers = packed.n_servers
+        self.f_arr, self.f_j = f_arr, to_device(f_arr)
+        self.capacity, self.epsilon = capacity, epsilon
+        self.check_capacity = capacity is not None or epsilon is not None
+        self.cap_j = jnp.asarray(np.broadcast_to(
+            np.asarray(np.inf if capacity is None else capacity, np.float32),
+            (n_servers,),
+        ))
+        self.eps_j = jnp.asarray(
+            np.float32(epsilon if epsilon is not None else np.inf)
         )
-        add_obj.append(additions[0])
-        add_srv.append(additions[1])
-        if len(seq_idx):
-            # exact fallback against the masked host view; additions are
-            # replayed into the masked words so later classes see them
-            if host_scheme is None:
-                host_scheme = ReplicationScheme(
-                    masked.unpack(), np.asarray(fshard, np.int32)
+        self.pol, self.backend, self.load = pol, backend, load
+        # the fused step has no pure-python form: a reference backend
+        # runs the separate-dispatch pipeline
+        self.fused = fused and backend != "reference"
+        self.use_pallas = self.fused and backend == "pallas"
+        if mesh is not None:
+            if not self.fused:
+                raise ValueError("mesh= requires fused=True")
+            nd = int(np.prod(list(mesh.shape.values())))
+            batch_size = -(-batch_size // nd) * nd
+        self.mesh, self.batch_size = mesh, batch_size
+        self.max_candidates, self.stats = max_candidates, stats
+        self.track_rm, self.collect = track_rm, collect
+        self.acc_holder = acc_holder
+        self._bind(packed, host_view, srv_load)
+
+    def _bind(self, packed: PackedScheme, host_view, srv_load=None) -> None:
+        """Point the pass at ``packed``: the routed gate, the fused gate's
+        holder rank and the per-server load follow its words."""
+        from repro.engine.backends import _load_vector  # lazy: no cycle
+
+        self.packed, self.host_view, self.added = packed, host_view, []
+        self.routed_fn = _routed_gate_fn(
+            packed, self.pol, self.backend, load=self.load
+        )
+        self.rank = None
+        if self.fused:
+            uses_load = self.pol is not None and self.pol.uses_load
+            self.rank = _load_vector(
+                self.load if uses_load else None, packed.words
+            )
+        if srv_load is None:
+            srv_load = packed.storage_per_server(self.f_arr)
+        self.srv_load = jnp.asarray(srv_load.astype(np.float32))
+
+    def masked(self, cmask_words: np.ndarray, fshard: np.ndarray,
+               orphans: np.ndarray) -> "_Pass":
+        """This pass as a loss case sees it, collecting its additions.
+
+        The words are the live ones with the lost servers' holder bits
+        cleared (``cmask_words``), sharded by the case's rotation-failover
+        homes ``fshard``.  ``orphans`` — the objects the case orphans, with
+        no copy at their failover home yet — are **re-homed first** (a
+        copy provisioned at the rotation target), because the UPDATE's
+        closed-form cost model prices every object as free at its own
+        home — an assumption the masked scheme breaks exactly at the
+        orphans (and the one a real system restores by resharding off a
+        dead server; re-homing is also what makes the data itself survive
+        the case).  Every candidate server is a failover home, hence alive
+        under the case by construction; capacity is checked on the masked
+        load, which equals the live load on every surviving server.  The
+        additions, orphan re-homes first, are for the caller to replay
+        into the live words (Thm 5.3: replaying them can only lower
+        latencies of the unmasked walk too).
+        """
+        from repro.engine.backends import mask_case_words  # lazy: no cycle
+
+        fshard = np.asarray(fshard, np.int32)
+        words = PackedScheme(
+            words=mask_case_words(self.packed.words, to_device(cmask_words)),
+            shard=to_device(fshard),
+            n_servers=self.packed.n_servers,
+        )
+        if len(orphans):
+            words.add(orphans, fshard[orphans])
+        case = copy.copy(self)
+        case.mesh, case.collect, case.acc_holder = None, True, None
+        case._bind(words, lambda: ReplicationScheme(words.unpack(), fshard))
+        if len(orphans):
+            case.added.append((np.asarray(orphans, np.int64),
+                               fshard[orphans].astype(np.int64)))
+        return case
+
+    def additions(self) -> tuple[np.ndarray, np.ndarray]:
+        """The collected (object, server) pairs as two int64 arrays."""
+        if not self.added:
+            return np.zeros(0, np.int64), np.zeros(0, np.int64)
+        return (np.concatenate([o for o, _ in self.added]),
+                np.concatenate([s for _, s in self.added]))
+
+    def provision(self, ps: PathSet, t: np.ndarray) -> None:
+        """The main pass of a call: :meth:`run` over every path, then, for a
+        policy-aware pass, the bounded routed revalidation."""
+        with obs.span("repro.greedy.classes"):
+            self.run(ps, t)
+        if self.routed_fn is None:
+            return
+        from repro.engine.incremental import PathIndex  # lazy: no cycle
+
+        with obs.span("repro.greedy.revalidate"):
+            _revalidate_routed(
+                self.routed_fn, ps, t, self.run, self.stats,
+                index=PathIndex(np.asarray(ps.objects), self.packed.n_objects),
+            )
+
+    def run(self, ps: PathSet, t: np.ndarray) -> None:
+        """Provision ``ps`` under its per-path budgets ``t``, budget class
+        by budget class (tightest first)."""
+        stats = self.stats
+        with obs.span("repro.greedy.plan"):
+            plan = _budget_class_plan(
+                ps, t, self.packed.shard, self.max_candidates,
+                skip_tables=self.routed_fn is not None, stats=stats,
+            )
+        for b, cls, vec_idx, seq_idx, h_all, tables, counts in plan:
+            n_skip = 0
+            if self.routed_fn is not None and cls.n_paths:
+                with obs.span("repro.greedy.filter"):
+                    vec_idx, seq_idx, tables, counts, n_skip = (
+                        _routed_class_filter(cls, b, h_all, self.routed_fn,
+                                             self.max_candidates, stats=stats))
+                stats.routed_skips += n_skip
+            _obs_record_class(stats, b, len(vec_idx), len(seq_idx), counts, n_skip)
+            with obs.span("repro.greedy.batches"):
+                _run_update_batches(
+                    self, cls.objects[vec_idx], cls.lengths[vec_idx], tables,
+                    counts, np.full(len(vec_idx), b, np.int32),
                 )
-            else:
-                host_scheme.mask = masked.unpack()
-            fb_obj: list[int] = []
-            fb_srv: list[int] = []
-            for i in seq_idx:
-                res = update_exact(
-                    host_scheme, cls.path(int(i)), b, f_arr, capacity,
-                    epsilon, policy=pol, load=load,
-                )
-                stats.fallback_paths += 1
-                if res.feasible:
+            if not len(seq_idx):
+                continue
+            # Exact fallback for enumeration-heavy paths (after the class's
+            # vectorized paths; order is immaterial to correctness by Thm
+            # 5.3), priced against the host view and replayed into the
+            # words so later classes see it.
+            with obs.span("repro.greedy.fallback"):
+                host = self.host_view()
+                fb_obj: list[int] = []
+                fb_srv: list[int] = []
+                for i in seq_idx:
+                    res = update_exact(
+                        host, cls.path(int(i)), b, self.f_arr, self.capacity,
+                        self.epsilon, policy=self.pol, load=self.load,
+                    )
+                    stats.fallback_paths += 1
+                    if not res.feasible:
+                        stats.failed_paths += 1
+                        continue
                     stats.total_cost += res.cost
                     fb_obj.extend(v for v, _ in res.additions)
                     fb_srv.extend(s for _, s in res.additions)
-                    if track_rm:
+                    if self.track_rm:
                         stats.rm.extend(res.rm_entries)
-                else:
-                    stats.failed_paths += 1
-            if fb_obj:
-                masked.add(np.asarray(fb_obj), np.asarray(fb_srv))
-                add_obj.append(np.asarray(fb_obj, np.int64))
-                add_srv.append(np.asarray(fb_srv, np.int64))
-                if check_capacity:
-                    srv_load = jnp.asarray(
-                        masked.storage_per_server(f_arr).astype(np.float32)
-                    )
-    return (
-        np.concatenate(add_obj) if add_obj else np.zeros(0, np.int64),
-        np.concatenate(add_srv) if add_srv else np.zeros(0, np.int64),
-    )
+                if fb_obj:
+                    fb = (np.asarray(fb_obj, np.int64),
+                          np.asarray(fb_srv, np.int64))
+                    self.packed.add(*fb)
+                    if self.collect:
+                        self.added.append(fb)
+                    if self.check_capacity:
+                        self.srv_load = jnp.asarray(
+                            self.packed.storage_per_server(self.f_arr)
+                            .astype(np.float32)
+                        )
 
 
-def _enforce_resilience(
-    packed: PackedScheme,
-    ps: PathSet,
-    t_path: np.ndarray,
-    res,
-    pol,
-    policy_backend: str,
-    f_arr: np.ndarray,
-    f_j,
-    capacity,
-    epsilon,
-    cap_j,
-    eps_j,
-    check_capacity: bool,
-    batch_size: int,
-    max_candidates: int,
-    stats: GreedyStats,
-    load,
-    fused: bool,
-    track_rm: bool,
-):
+def _enforce_resilience(pass_: _Pass, ps: PathSet, t_path: np.ndarray, res):
     """The k-resilience gate: repair every loss case until none violates.
 
     Per bounded round: evaluate h under every loss case of ``res`` (one
@@ -1126,6 +1103,7 @@ def _enforce_resilience(
         failover_shard,
     )
 
+    packed, stats = pass_.packed, pass_.stats
     n_servers = packed.n_servers
     with obs.span("repro.greedy.resilience.homes"):
         shard_host = to_host(packed.shard)
@@ -1137,7 +1115,8 @@ def _enforce_resilience(
     for rnd in range(_RESILIENCE_ROUNDS + 1):
         with obs.span("repro.greedy.resilience.eval", round=rnd):
             h_cases = _resilient_eval(
-                packed, ps, cases, homes, pol, policy_backend, load
+                packed, ps, cases, homes, pass_.pol, pass_.backend,
+                pass_.load,
             )
             viol = h_cases > t_path[None, :]
             total = int(viol.sum())
@@ -1167,13 +1146,9 @@ def _enforce_resilience(
                 orphans = vobj[
                     dead[shard_host[vobj]] & ~mask_host[vobj, homes[d][vobj]]
                 ]
-                obj, srv = _repair_loss_case(
-                    packed, ps.select(idx), t_path[idx], homes[d],
-                    case_word_mask(c, W), orphans, pol, policy_backend,
-                    f_arr, f_j, capacity, epsilon, cap_j, eps_j,
-                    check_capacity, batch_size, max_candidates, stats, load,
-                    fused, track_rm,
-                )
+                case = pass_.masked(case_word_mask(c, W), homes[d], orphans)
+                case.run(ps.select(idx), t_path[idx])
+                obj, srv = case.additions()
             if obs.enabled():
                 obs.REGISTRY.counter("repro.greedy.resilience.orphans").inc(
                     len(orphans))
@@ -1346,106 +1321,14 @@ def replicate_workload(
 
         f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
         packed = PackedScheme.from_sharding(scheme.shard, n_servers)
-        shard_j = packed.shard
-        f_j = to_device(f_arr)
-
-        check_capacity, cap_j, eps_j = _capacity_arrays(
-            n_servers, capacity, epsilon
-        )
-        srv_load = jnp.asarray(
-            scheme.storage_per_server(f_arr).astype(np.float32)
-        )
-        routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
-        fused = fused and policy_backend != "reference"
-        use_pallas = policy_backend == "pallas"
-        rank, batch_size = _fused_setup(
-            packed, pol, load, fused, mesh, batch_size
+        pass_ = _Pass(
+            packed, f_arr, capacity, epsilon, pol, policy_backend, load,
+            fused, mesh, batch_size, max_candidates, stats, track_rm,
+            host_view=lambda: ReplicationScheme(packed.unpack(), scheme.shard),
+            srv_load=scheme.storage_per_server(f_arr),
         )
 
-    def run_classes(ps_run: PathSet, t_run: np.ndarray) -> None:
-        nonlocal srv_load
-        with obs.span("repro.greedy.plan"):
-            plan = _budget_class_plan(
-                ps_run, t_run, shard_j, max_candidates,
-                skip_tables=routed_fn is not None, stats=stats,
-            )
-        for b, cls, vec_idx, seq_idx, h_all, tables, counts in plan:
-            n_skip = 0
-            if routed_fn is not None and cls.n_paths:
-                with obs.span("repro.greedy.filter"):
-                    vec_idx, seq_idx, tables, counts, n_skip = (
-                        _routed_class_filter(cls, b, h_all, routed_fn,
-                                             max_candidates, stats=stats))
-                stats.routed_skips += n_skip
-            _obs_record_class(stats, b, len(vec_idx), len(seq_idx), counts, n_skip)
-            with obs.span("repro.greedy.batches"):
-                srv_load, _ = _run_update_batches(
-                    packed,
-                    cls.objects[vec_idx],
-                    cls.lengths[vec_idx],
-                    shard_j,
-                    f_arr,
-                    f_j,
-                    tables,
-                    counts,
-                    np.full(len(vec_idx), b, np.int32),
-                    srv_load,
-                    cap_j,
-                    eps_j,
-                    check_capacity,
-                    batch_size,
-                    stats,
-                    track_rm,
-                    routed_fn=None if fused else routed_fn,
-                    fused=fused,
-                    pol=pol,
-                    rank=rank,
-                    use_pallas=use_pallas,
-                    mesh=mesh,
-                )
-
-            # Exact fallback for enumeration-heavy paths (processed after
-            # the class's vectorized paths; order is immaterial to
-            # correctness by Thm 5.3).  Additions run against a freshly
-            # synced host mask and are replayed into the packed words so
-            # later classes see them.
-            if len(seq_idx):
-                with obs.span("repro.greedy.fallback"):
-                    scheme.mask = packed.unpack()
-                    fb_obj: list[int] = []
-                    fb_srv: list[int] = []
-                    for i in seq_idx:
-                        res = update_exact(
-                            scheme, cls.path(int(i)), b, f_arr, capacity,
-                            epsilon, policy=pol, load=load,
-                        )
-                        stats.fallback_paths += 1
-                        if res.feasible:
-                            stats.total_cost += res.cost
-                            fb_obj.extend(v for v, _ in res.additions)
-                            fb_srv.extend(s for _, s in res.additions)
-                            if track_rm:
-                                stats.rm.extend(res.rm_entries)
-                        else:
-                            stats.failed_paths += 1
-                    if fb_obj:
-                        packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
-                        if check_capacity:
-                            srv_load = jnp.asarray(
-                                packed.storage_per_server(f_arr)
-                                .astype(np.float32)
-                            )
-
-    with obs.span("repro.greedy.classes"):
-        run_classes(ps, t_path)
-    if routed_fn is not None:
-        from repro.engine.incremental import PathIndex  # lazy: no cycle
-
-        with obs.span("repro.greedy.revalidate"):
-            _revalidate_routed(
-                routed_fn, ps, t_path, run_classes, stats,
-                index=PathIndex(np.asarray(ps.objects), packed.n_objects),
-            )
+    pass_.provision(ps, t_path)
 
     # single host readback of the packed words (vs. per-batch bool mask);
     # fallback additions were replayed into the words, so the packed state
@@ -1465,15 +1348,12 @@ def replicate_workload(
             if stats.pruned_replicas:
                 # removals are not monotone: the packed words are stale
                 with obs.span("repro.greedy.prune.repack"):
-                    packed = PackedScheme.from_mask(scheme.mask, scheme.shard)
+                    packed = pass_.packed = PackedScheme.from_mask(
+                        scheme.mask, scheme.shard)
 
     if res is not None and ps.n_paths:
         with obs.span("repro.greedy.resilience"):
-            _enforce_resilience(
-                packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
-                capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
-                max_candidates, stats, load, fused, track_rm,
-            )
+            _enforce_resilience(pass_, ps, t_path, res)
             with obs.span("repro.greedy.resilience.unpack"):
                 scheme.mask = packed.unpack()
 
@@ -1484,7 +1364,6 @@ def replicate_workload(
     return scheme, stats
 
 
-@_call_span("repro.greedy.delta")
 def replicate_delta(
     pathset: PathSet,
     engine: LatencyEngine,
@@ -1501,14 +1380,11 @@ def replicate_delta(
     load: np.ndarray | None = None,
     fused: bool = False,
     mesh=None,
-    collect_additions: bool = True,
-    stats_acc: DeviceStatsAcc | None = None,
-    sync_host: bool = True,
     resilience=None,
 ):
     """Warm-start incremental UPDATE over *delta* paths (online serving).
 
-    Runs the same batched Alg 2 UPDATE loop as :func:`replicate_workload`,
+    Runs the same batched Alg 2 UPDATE pass as :func:`replicate_workload`,
     but against the scheme an existing :class:`LatencyEngine` already holds
     device-resident — no from-scratch rebuild, no re-upload.  The additions
     are scatter-ORed into the engine's ``PackedScheme`` on device and
@@ -1539,20 +1415,6 @@ def replicate_delta(
     Returns ``(stats, (objects, servers))`` — the greedy stats for the
     delta and the applied replica additions as two int64 arrays (the
     scheme delta a controller ships to the cluster / replays on restart).
-    With ``collect_additions=False`` (streamed ingestion: the caller only
-    wants the evolving scheme, not the delta) the per-batch chosen-mask
-    readbacks are skipped entirely and the returned arrays are empty; the
-    engine's host mask, when present, is refreshed from the packed words
-    once per class instead of per-pair.
-
-    ``stats_acc`` (fused runs) keeps the device stat accumulator live
-    across calls instead of reading it back before returning — the
-    returned stats' cost/failed/skipped components stay 0 until the
-    caller :meth:`DeviceStatsAcc.drain`\\ s the holder.  ``sync_host=False``
-    additionally skips the end-of-call host-mask refresh (the other
-    per-call sync point).  Together they make a fused, non-policy call
-    fully asynchronous — what :func:`replicate_stream`'s double-buffered
-    pipeline needs to overlap chunk ingestion with device compute.
 
     ``resilience`` mirrors :func:`replicate_workload`: after the delta
     pass the loss cases are re-walked over the delta paths and repaired;
@@ -1560,6 +1422,24 @@ def replicate_delta(
     repairing a failure passes the dead set as a one-domain
     ``KResilient`` to provision survivable copies in the same call).
     """
+    return _delta(
+        pathset, engine, t, f=f, capacity=capacity, epsilon=epsilon,
+        batch_size=batch_size, max_candidates=max_candidates, prune=prune,
+        track_rm=track_rm, policy=policy, policy_backend=policy_backend,
+        load=load, fused=fused, mesh=mesh, resilience=resilience,
+    )
+
+
+@_call_span("repro.greedy.delta")
+def _delta(pathset, engine, t, *, f, capacity, epsilon, batch_size,
+           max_candidates, prune, track_rm, policy, policy_backend, load,
+           fused, mesh, resilience, collect=True, acc_holder=None):
+    """:func:`replicate_delta`'s body.  ``collect=False`` is the streamed
+    chunk (:func:`replicate_stream`): no per-batch readback of the chosen
+    pairs, the engine's host mirror refreshed from the words only where
+    an exact fallback prices against it, and no sync at return; with
+    ``acc_holder`` the fused stat readback is deferred too, so a fused,
+    non-policy chunk is fully asynchronous."""
     from repro.core.slo import normalize_path_budgets  # local: no cycle at import
     from repro.engine.resilience import resolve_resilience  # local: no cycle
     from repro.engine.routing import resolve_policy  # local: no cycle at import
@@ -1575,8 +1455,6 @@ def replicate_delta(
             )
         packed = engine.packed
         shard = engine.host_shard()
-        n = packed.n_objects
-        n_servers = packed.n_servers
         t_path = normalize_path_budgets(t, pathset)
         if prune:
             ps, keep = pathset.prune_redundant(
@@ -1587,179 +1465,51 @@ def replicate_delta(
             ps = pathset
         stats = GreedyStats(rm=[] if track_rm else None)
         stats.paths_processed = ps.n_paths
-        empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
         if ps.n_paths == 0:
             stats.runtime_s = time.perf_counter() - t0
-            return stats, empty
+            return stats, (np.zeros(0, np.int64), np.zeros(0, np.int64))
 
-        f_arr = np.ones((n,), np.float32) if f is None else f.astype(np.float32)
-        f_j = to_device(f_arr)
-        shard_j = packed.shard
+        def host_view():
+            if engine.scheme is None:
+                return engine.to_scheme()
+            if collect:
+                # the mirror takes what the words gained in this call
+                obj, srv = pass_.additions()
+                engine.scheme.mask[obj, srv] = True
+            else:
+                # no per-pair readback: refresh the mirror from the packed
+                # truth (one readback) right before the fallback uses it
+                engine.scheme.mask = packed.unpack()
+                if obs.enabled():
+                    obs.REGISTRY.counter("repro.greedy.mask_syncs").inc()
+            return engine.scheme
 
-        check_capacity, cap_j, eps_j = _capacity_arrays(
-            n_servers, capacity, epsilon
+        f_arr = (np.ones((packed.n_objects,), np.float32) if f is None
+                 else f.astype(np.float32))
+        pass_ = _Pass(
+            packed, f_arr, capacity, epsilon, pol, policy_backend, load,
+            fused, mesh, batch_size, max_candidates, stats, track_rm,
+            host_view, collect=collect, acc_holder=acc_holder,
         )
-        srv_load = jnp.asarray(
-            packed.storage_per_server(f_arr).astype(np.float32)
-        )
-        routed_fn = _routed_gate_fn(packed, pol, policy_backend, load=load)
-        fused = fused and policy_backend != "reference"
-        use_pallas = policy_backend == "pallas"
-        rank, batch_size = _fused_setup(
-            packed, pol, load, fused, mesh, batch_size
-        )
 
-    add_obj = np.zeros(0, np.int64)
-    add_srv = np.zeros(0, np.int64)
-
-    def run_classes(ps_run: PathSet, t_run: np.ndarray) -> None:
-        nonlocal srv_load, add_obj, add_srv
-        with obs.span("repro.greedy.plan"):
-            plan = _budget_class_plan(
-                ps_run, t_run, shard_j, max_candidates,
-                skip_tables=routed_fn is not None, stats=stats,
-            )
-        for b, cls, vec_idx, seq_idx, h_all, tables, counts in plan:
-            n_skip = 0
-            if routed_fn is not None and cls.n_paths:
-                with obs.span("repro.greedy.filter"):
-                    vec_idx, seq_idx, tables, counts, n_skip = (
-                        _routed_class_filter(cls, b, h_all, routed_fn,
-                                             max_candidates, stats=stats))
-                stats.routed_skips += n_skip
-            _obs_record_class(stats, b, len(vec_idx), len(seq_idx), counts, n_skip)
-            with obs.span("repro.greedy.batches"):
-                srv_load, additions = _run_update_batches(
-                    packed,
-                    cls.objects[vec_idx],
-                    cls.lengths[vec_idx],
-                    shard_j,
-                    f_arr,
-                    f_j,
-                    tables,
-                    counts,
-                    np.full(len(vec_idx), b, np.int32),
-                    srv_load,
-                    cap_j,
-                    eps_j,
-                    check_capacity,
-                    batch_size,
-                    stats,
-                    track_rm,
-                    collect_additions=collect_additions,
-                    routed_fn=None if fused else routed_fn,
-                    fused=fused,
-                    pol=pol,
-                    rank=rank,
-                    use_pallas=use_pallas,
-                    mesh=mesh,
-                    acc_holder=stats_acc if fused else None,
-                )
-
-            # Mirror the vectorized additions into the host scheme FIRST:
-            # the exact fallback below prices candidates against the host
-            # mask, which must reflect what this class already
-            # scatter-ORed into the words (and later classes' fallbacks
-            # price against this class).
-            if collect_additions:
-                cls_obj, cls_srv = additions
-                if engine.scheme is not None and len(cls_obj):
-                    engine.scheme.mask[cls_obj, cls_srv] = True
-                add_obj = np.concatenate([add_obj, cls_obj])
-                add_srv = np.concatenate([add_srv, cls_srv])
-
-            # Exact fallback for enumeration-heavy delta paths: run against
-            # a host scheme and replay the additions into the
-            # device-resident words.
-            if len(seq_idx):
-                with obs.span("repro.greedy.fallback"):
-                    if not collect_additions and engine.scheme is not None:
-                        # no per-pair readback requested: the fallback
-                        # prices against the host mask, so refresh it from
-                        # the packed truth (one readback) right before use
-                        engine.scheme.mask = packed.unpack()
-                        if obs.enabled():
-                            obs.REGISTRY.counter(
-                                "repro.greedy.mask_syncs").inc()
-                    host = (
-                        engine.scheme
-                        if engine.scheme is not None
-                        else engine.to_scheme()
-                    )
-                    fb_obj: list[int] = []
-                    fb_srv: list[int] = []
-                    for i in seq_idx:
-                        res = update_exact(
-                            host, cls.path(int(i)), b, f_arr, capacity,
-                            epsilon, policy=pol, load=load,
-                        )
-                        stats.fallback_paths += 1
-                        if res.feasible:
-                            stats.total_cost += res.cost
-                            fb_obj.extend(v for v, _ in res.additions)
-                            fb_srv.extend(s for _, s in res.additions)
-                            if track_rm:
-                                stats.rm.extend(res.rm_entries)
-                        else:
-                            stats.failed_paths += 1
-                    if fb_obj:
-                        packed.add(np.asarray(fb_obj), np.asarray(fb_srv))
-                        if collect_additions:
-                            add_obj = np.concatenate(
-                                [add_obj, np.asarray(fb_obj, np.int64)]
-                            )
-                            add_srv = np.concatenate(
-                                [add_srv, np.asarray(fb_srv, np.int64)]
-                            )
-                        if check_capacity:
-                            srv_load = jnp.asarray(
-                                packed.storage_per_server(f_arr)
-                                .astype(np.float32)
-                            )
-
-    with obs.span("repro.greedy.classes"):
-        run_classes(ps, t_path)
-    if routed_fn is not None:
-        from repro.engine.incremental import PathIndex  # lazy: no cycle
-
-        with obs.span("repro.greedy.revalidate"):
-            _revalidate_routed(
-                routed_fn, ps, t_path, run_classes, stats,
-                index=PathIndex(np.asarray(ps.objects), packed.n_objects),
-            )
+    pass_.provision(ps, t_path)
 
     if res is not None:
         with obs.span("repro.greedy.resilience"):
-            r_obj, r_srv = _enforce_resilience(
-                packed, ps, t_path, res, pol, policy_backend, f_arr, f_j,
-                capacity, epsilon, cap_j, eps_j, check_capacity, batch_size,
-                max_candidates, stats, load, fused, track_rm,
-            )
-        if len(r_obj):
-            if engine.scheme is not None:
-                engine.scheme.mask[r_obj, r_srv] = True
-            add_obj = np.concatenate([add_obj, r_obj])
-            add_srv = np.concatenate([add_srv, r_srv])
+            pass_.added.append(_enforce_resilience(pass_, ps, t_path, res))
+    add_obj, add_srv = pass_.additions()
 
     # the UPDATE loop scatter-ORs into packed.words inside jits, bypassing
     # engine.add_replicas — report the touched objects so the engine's
     # incremental latency cache invalidates its exact dirty set.  The
     # additions are copies of objects on the processed paths, so with the
     # per-batch readbacks off the conservative superset is all of them.
-    if collect_additions:
+    if collect:
+        if engine.scheme is not None and len(add_obj):
+            engine.scheme.mask[add_obj, add_srv] = True
         engine.note_changed(add_obj)
     else:
         engine.note_changed(np.asarray(ps.objects))
-
-    if not collect_additions and sync_host and engine.scheme is not None:
-        # keep the engine's host mirror consistent at return (the per-pair
-        # incremental mirror is what collect_additions=False skipped);
-        # sync_host=False defers even this to the caller (streamed
-        # ingestion syncs once at stream end)
-        with obs.span("repro.greedy.unpack"):
-            engine.scheme.mask = packed.unpack()
-        if obs.enabled():
-            obs.REGISTRY.counter("repro.greedy.mask_syncs").inc()
 
     # Dedupe (a batch can choose the same (v, s) for several paths; the
     # scatter-OR is idempotent, but the returned delta is the exact set of
@@ -1799,8 +1549,8 @@ def replicate_stream(
     is wrapped in one): the producer builds each chunk on demand and
     drops it after the yield, so host residency peaks at one chunk
     (``stats.peak_resident_paths`` — the contract
-    ``benchmarks/provisioning_scale.py`` asserts).  Each chunk runs the
-    warm-started incremental UPDATE (:func:`replicate_delta`) against the
+    ``benchmarks/provisioning_scale.py`` asserts).  Each chunk runs
+    :func:`replicate_delta`'s warm-started incremental UPDATE against the
     single device-resident packed scheme; by Thm 5.3 replica additions
     are monotone, so chunked provisioning is exactly as sound as one long
     run with different batch boundaries (paths duplicated across chunks
@@ -1808,9 +1558,9 @@ def replicate_stream(
 
     ``t`` is the default budget for chunks yielded without one; chunks
     yielded as ``(PathSet, budgets)`` override it per chunk.  ``fused``
-    defaults on (this is the provisioning-scale entry point) and, with
-    ``collect_additions`` off internally, no per-batch readback ever
-    crosses the bus.
+    defaults on (this is the provisioning-scale entry point) and, as the
+    chunks collect no additions, no per-batch readback ever crosses the
+    bus.
 
     Ingestion is **double-buffered** (the engine's ``stream_chunks``
     pipeline shape, applied to provisioning): each chunk's UPDATE passes
@@ -1835,8 +1585,7 @@ def replicate_stream(
     scheme = ReplicationScheme.from_sharding(shard, n_servers)
     engine = LatencyEngine(scheme)
     stats = GreedyStats()
-    fused = fused and policy_backend != "reference"
-    acc_holder = DeviceStatsAcc() if fused else None
+    acc_holder = DeviceStatsAcc()
 
     def dispatch(item):
         ps, t_chunk = item
@@ -1845,12 +1594,12 @@ def replicate_stream(
             raise ValueError(
                 "no latency budget: pass t= or stream (PathSet, t) tuples"
             )
-        cstats, _ = replicate_delta(
+        cstats, _ = _delta(
             ps, engine, budgets, f=f, capacity=capacity, epsilon=epsilon,
             batch_size=batch_size, max_candidates=max_candidates,
-            prune=prune, policy=policy, policy_backend=policy_backend,
-            load=load, fused=fused, mesh=mesh, collect_additions=False,
-            stats_acc=acc_holder, sync_host=False,
+            prune=prune, track_rm=False, policy=policy,
+            policy_backend=policy_backend, load=load, fused=fused,
+            mesh=mesh, resilience=None, collect=False, acc_holder=acc_holder,
         )
         # cost/failed/skipped live in the deferred device accumulator
         # (fused) and drain once after the stream; the host-side components
@@ -1870,12 +1619,10 @@ def replicate_stream(
 
     with obs.span("repro.greedy.stream"):
         overlap_s = double_buffer(stream, dispatch)
-        if acc_holder is not None:
-            acc_holder.drain(stats)
+        acc_holder.drain(stats)
         if engine.packed is not None:
-            # the one end-of-stream host sync the per-chunk sync_host=False
-            # deferred (keeps scheme and the engine's host mirror
-            # consistent)
+            # the one end-of-stream host sync the chunks deferred (keeps
+            # scheme and the engine's host mirror consistent)
             with obs.span("repro.greedy.unpack"):
                 scheme.mask = engine.packed.unpack()
     stats.ingest_overlap_s = stream.stats.ingest_overlap_s = overlap_s
